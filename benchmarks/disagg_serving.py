@@ -25,6 +25,7 @@ import sys
 
 if __name__ == "__main__":
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 
@@ -116,7 +117,10 @@ def main() -> int:
             f"{tps_dis_pf:.1f} vs {tps_mono_pf:.1f} tok/s (ratio "
             f"{ratio:.3f})")
 
+    import jax
     print(json.dumps({
+        # the numbers below are host-device (CPU) figures, never chip ones
+        "platform": jax.devices()[0].platform,
         "token_parity": int(parity),
         "handoffs": int(sd["handoffs"]),
         "handoff_pages": int(sd["handoff_pages"]),

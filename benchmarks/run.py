@@ -773,6 +773,7 @@ def _disagg_bench():
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src")) \
         + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"       # virtual host devices by design
     out = subprocess.run([sys.executable, script], capture_output=True,
                          text=True, timeout=1800, env=env)
     if out.returncode != 0:
@@ -799,6 +800,8 @@ def main(argv=None) -> int:
                          "slotted ratio, overheads) from warnings into "
                          "failures")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     os.makedirs(OUT_DIR, exist_ok=True)
     try:                                           # python -m benchmarks.run

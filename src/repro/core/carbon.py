@@ -178,6 +178,36 @@ def load_trace_csv(path: str, name: Optional[str] = None,
 # =============================================================================
 # accounting
 # =============================================================================
+class CompensatedSum:
+    """Running float total, bit-identical to the builtin ``sum()`` of the
+    same values in the same order (Neumaier compensation, as CPython's
+    ``sum`` does for floats).  The one summation rule of the carbon totals:
+    ``CarbonAccountant`` and ``obs.carbon_feed.CarbonFeed`` both accumulate
+    segments through it, so their totals agree to the last bit."""
+
+    __slots__ = ("_hi", "_c")
+
+    def __init__(self) -> None:
+        self._hi = 0.0
+        self._c = 0.0
+
+    def add(self, x: float) -> float:
+        """Fold ``x`` in; returns the new total."""
+        x = float(x)
+        t = self._hi + x
+        if abs(self._hi) >= abs(x):
+            self._c += (self._hi - t) + x
+        else:
+            self._c += (x - t) + self._hi
+        self._hi = t
+        return self.value
+
+    @property
+    def value(self) -> float:
+        c = self._c
+        return self._hi + c if c and math.isfinite(c) else self._hi
+
+
 @dataclasses.dataclass
 class CarbonAccountant:
     """Integrates energy → operational carbon at time-varying intensity.
@@ -190,6 +220,10 @@ class CarbonAccountant:
     # add() forwards its EXACT joules/grams, so feed totals equal the
     # accountant's with no re-derivation (conservation by construction)
     feed: Optional[object] = None
+    _e_sum: CompensatedSum = dataclasses.field(
+        default_factory=CompensatedSum, repr=False, compare=False)
+    _g_sum: CompensatedSum = dataclasses.field(
+        default_factory=CompensatedSum, repr=False, compare=False)
 
     def add(self, t_start: float, duration_s: float, power_w: float) -> float:
         """Account ``power_w`` drawn for ``duration_s`` starting at t_start.
@@ -197,8 +231,8 @@ class CarbonAccountant:
         e_j = power_w * duration_s
         ci = self.trace.at(t_start + 0.5 * duration_s)   # midpoint rule
         g = (e_j / 3.6e6) * ci * self.pue                # J → kWh → gCO2
-        self.energy_j += e_j
-        self.carbon_g += g
+        self.energy_j = self._e_sum.add(e_j)
+        self.carbon_g = self._g_sum.add(g)
         if self.feed is not None:
             self.feed.record_segment(t_start, duration_s, e_j, g)
         return g

@@ -132,11 +132,8 @@ def greedy_shift(jobs: Sequence[DeferrableJob], slots: Sequence[Slot],
 def lp_shift(jobs: Sequence[DeferrableJob], slots: Sequence[Slot],
              pue: float = PUE_DEFAULT) -> ShiftPlan:
     """Exact LP over the feasible (job, slot) pairs; see module docstring."""
-    try:
-        from scipy.optimize import linprog
-        from scipy.sparse import lil_matrix
-    except ImportError:                       # pragma: no cover - baked in
-        return greedy_shift(jobs, slots, pue)
+    from scipy.optimize import linprog
+    from scipy.sparse import lil_matrix
 
     pairs: List[Tuple[int, int]] = [(j, s) for j, job in enumerate(jobs)
                                     for s, slot in enumerate(slots)

@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -132,7 +130,7 @@ def paged_decode_attention(q: jnp.ndarray, k_arena: jnp.ndarray,
         functools.partial(_paged_kernel, sm_scale=dh ** -0.5, block_size=bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, K, g, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lengths_arr, qr, kr, vr)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_scr, *, chunk: int):
@@ -105,7 +103,7 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(A.astype(jnp.float32), xr, dtr, Br, Cr)

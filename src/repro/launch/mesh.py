@@ -12,6 +12,7 @@ adds a leading "pod" axis (2 pods = 512 chips) connected over DCN.  Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # XLA flags a real TPU deployment would launch with (latency-hiding overlap of
 # collectives with compute; documented here, applied by launch scripts).
@@ -22,6 +23,14 @@ TPU_XLA_PERF_FLAGS = (
     "--xla_enable_async_all_gather=true "
     "--xla_enable_async_collective_permute=true "
 )
+
+
+def _auto_mesh(shape, names):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules
+    (``sharding.rules``) annotate inputs and let the compiler propagate,
+    which ``Explicit`` axes (the ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def make_mesh_for(n_devices: int | None = None,
@@ -41,12 +50,12 @@ def make_mesh_for(n_devices: int | None = None,
     if m <= 0 or n % m != 0:
         raise ValueError(
             f"model_parallel={m} does not divide n_devices={n}")
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return _auto_mesh((n // m, m), ("data", "model"))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     if multi_pod:
-        return jax.make_mesh((2, 16, 16), ("pod", "data", "model"))
+        return _auto_mesh((2, 16, 16), ("pod", "data", "model"))
     return make_mesh_for(256, model_parallel=16)
 
 
@@ -54,8 +63,8 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small host-device mesh for unit tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count >= data*model*max(pod,1))."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_slice_mesh(devices, model_parallel: int):

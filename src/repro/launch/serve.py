@@ -109,6 +109,8 @@ def main(argv=None) -> int:
     ap.add_argument("--lam", type=float, default=0.1)
     ap.add_argument("--requests", type=int, default=16)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return run_sim(args) if args.mode == "sim" else run_real(args)
 
 

@@ -151,6 +151,26 @@ def _rope_tables(cfg: ModelConfig, positions: jnp.ndarray,
 
 
 # =============================================================================
+# LM head
+# =============================================================================
+def lm_logits(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Readout over the padded vocabulary (tied table or ``lm_head``).  The
+    padding rows past ``cfg.vocab_size`` exist only for even sharding: their
+    logits are pinned to the dtype's lowest value, so no greedy step or
+    softmax ever picks a token outside the vocabulary."""
+    if cfg.tie_embeddings:
+        logits = L.embedding_logits(params["embed"], x)
+    else:
+        logits = L.dense_apply(params["lm_head"], x)
+    if logits.shape[-1] > cfg.vocab_size:
+        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                       logits.ndim - 1)
+        logits = jnp.where(ids < cfg.vocab_size, logits,
+                           jnp.finfo(logits.dtype).min)
+    return logits
+
+
+# =============================================================================
 # forward (train / prefill)
 # =============================================================================
 def forward_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
@@ -233,10 +253,7 @@ def forward_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
         return x, aux
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     return logits, aux
 
 
@@ -368,10 +385,7 @@ def decode_step_lm(params: Params, cache: Params, tokens: jnp.ndarray,
             new_cache = {"k": k2, "v": v2, "pos": pos + 1}
 
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     return logits[:, 0, :], new_cache
 
 
@@ -436,10 +450,7 @@ def prefill_kv_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig
     x, (k_all, v_all) = jax.lax.scan(body, x,
                                      (params["layers"], windows, is_global))
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     return logits, k_all, v_all
 
 
@@ -481,10 +492,7 @@ def decode_slots_lm(params: Params, cache: Params, tokens: jnp.ndarray,
     x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], cache["k"],
                                          cache["v"], windows, is_global))
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     new_cache = {"k": k2, "v": v2,
                  "lengths": lengths + active.astype(jnp.int32)}
     return logits[:, 0, :], new_cache
@@ -566,10 +574,7 @@ def prefill_paged_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], arena["k"],
                                          arena["v"], windows, is_global))
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     return logits, {"k": k2, "v": v2}
 
 
@@ -620,10 +625,7 @@ def decode_paged_lm(params: Params, arena: Params, tokens: jnp.ndarray,
     x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], arena["k"],
                                          arena["v"], windows, is_global))
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.embedding_logits(params["embed"], x)
-    else:
-        logits = L.dense_apply(params["lm_head"], x)
+    logits = lm_logits(params, x, cfg)
     return logits[:, 0, :], {"k": k2, "v": v2}
 
 

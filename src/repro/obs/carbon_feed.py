@@ -30,6 +30,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, List, Optional, Union
 
+from repro.core.carbon import CompensatedSum
+
 __all__ = ["CarbonFeed", "CarbonSnapshot"]
 
 _J_PER_KWH = 3.6e6
@@ -70,9 +72,11 @@ class CarbonFeed:
         self.carbon_g_total = 0.0
         self.snapshots: List[CarbonSnapshot] = []
         self._subs: List[Callable[[CarbonSnapshot], None]] = []
-        # current accumulation window
-        self._win_j = 0.0
-        self._win_g = 0.0
+        # running totals of every ingested segment, under the accountant's
+        # summation rule; a window is the difference of the totals at its
+        # two emissions, so the snapshots' joules sum back to the total
+        self._e_sum = CompensatedSum()
+        self._g_sum = CompensatedSum()
         self._win_t0: Optional[float] = None  # start of the open window
         self._last_sample_t: Optional[float] = None
 
@@ -89,8 +93,8 @@ class CarbonFeed:
             carbon_g = energy_j / _J_PER_KWH * ci * self.pue
         if self._win_t0 is None:
             self._win_t0 = float(t_start)
-        self._win_j += float(energy_j)
-        self._win_g += float(carbon_g)
+        self._e_sum.add(energy_j)
+        self._g_sum.add(carbon_g)
         self.heartbeat(t_start + duration_s)
 
     def sample(self, t: float, power_w: float) -> None:
@@ -113,19 +117,19 @@ class CarbonFeed:
         width = t - self._win_t0
         if not force and width < self.interval_s:
             return None
-        self.energy_j_total += self._win_j
-        self.carbon_g_total += self._win_g
+        win_j = self._e_sum.value - self.energy_j_total
+        win_g = self._g_sum.value - self.carbon_g_total
+        self.energy_j_total = self._e_sum.value
+        self.carbon_g_total = self._g_sum.value
         snap = CarbonSnapshot(
             t=float(t), region=self.region, window_s=float(width),
-            energy_j=self._win_j, carbon_g=self._win_g,
-            power_w=self._win_j / width if width > 0 else 0.0,
+            energy_j=win_j, carbon_g=win_g,
+            power_w=win_j / width if width > 0 else 0.0,
             ci_g_per_kwh=float(self.ci_fn(t)),
             energy_j_total=self.energy_j_total,
             carbon_g_total=self.carbon_g_total,
             sla_ok_frac=sla_ok_frac)
         self.snapshots.append(snap)
-        self._win_j = 0.0
-        self._win_g = 0.0
         self._win_t0 = None
         for cb in self._subs:
             cb(snap)
@@ -146,4 +150,4 @@ class CarbonFeed:
     @property
     def pending_energy_j(self) -> float:
         """Joules ingested but not yet emitted in a snapshot."""
-        return self._win_j
+        return self._e_sum.value - self.energy_j_total

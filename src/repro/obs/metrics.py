@@ -89,7 +89,12 @@ class Histogram:
 
     @property
     def sum(self) -> float:
-        return float(sum(self.samples))
+        # plain left-to-right addition, the rule of the running
+        # ``StreamingHistogram`` sum (the builtin ``sum()`` compensates)
+        total = 0.0
+        for v in self.samples:
+            total += v
+        return total
 
     @property
     def mean(self) -> float:

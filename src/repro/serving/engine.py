@@ -107,13 +107,16 @@ class EngineVariant:
 
 def build_engine_family(base_cfg: ModelConfig, fracs=(1.0, 0.5, 0.25),
                         seed: int = 0) -> List[EngineVariant]:
-    """Instantiate a real quality ladder by depth reduction."""
+    """Instantiate a real quality ladder by depth reduction.  Parameters are
+    drawn by one jitted program per rung: op-by-op initialisation of the
+    full-width ladder spent minutes dispatching on the chip."""
     out = []
     for i, frac in enumerate(sorted(fracs)):
         n_layers = max(int(base_cfg.n_layers * frac), 1)
         cfg = base_cfg.with_(n_layers=n_layers,
                              name=f"{base_cfg.name}-x{frac:g}")
-        params = R.init_params(jax.random.PRNGKey(seed), cfg)
+        params = jax.jit(R.init_params, static_argnums=1)(
+            jax.random.PRNGKey(seed), cfg)
         n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
         v = Variant(family=base_cfg.name, name=f"x{frac:g}", quality=i + 1,
                     accuracy=0.80 + 0.05 * i, flops_g=n_params * 2 / 1e9,
@@ -124,9 +127,12 @@ def build_engine_family(base_cfg: ModelConfig, fracs=(1.0, 0.5, 0.25),
 
 def _write_slot(cache_k, cache_v, lengths, k_all, v_all, slot, true_len):
     """Write one prefill's K/V into a slot and set its length (jitted so the
-    two cache updates fuse into one dispatch; slot/true_len stay traced)."""
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k_all, (0, slot, 0, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v_all, (0, slot, 0, 0, 0))
+    two cache updates fuse into one dispatch; slot/true_len stay traced).
+    K/V arrive in the model's dtype and are stored in the cache's."""
+    cache_k = jax.lax.dynamic_update_slice(
+        cache_k, k_all.astype(cache_k.dtype), (0, slot, 0, 0, 0))
+    cache_v = jax.lax.dynamic_update_slice(
+        cache_v, v_all.astype(cache_v.dtype), (0, slot, 0, 0, 0))
     return cache_k, cache_v, lengths.at[slot].set(true_len)
 
 
@@ -1032,11 +1038,8 @@ class PagedInstance:
             tree_blocks = self.prefix.live_prefix_blocks(seq.prompt, limit=nb)
         t_d2h = time.perf_counter()
         img_k, img_v = self._fns["gather_pages"](self.arena, jnp.asarray(idx))
-        for img in (img_k, img_v):
-            try:
-                img.copy_to_host_async()
-            except AttributeError:       # non-jax array stand-ins in tests
-                pass
+        img_k.copy_to_host_async()
+        img_v.copy_to_host_async()
         self.profiler.observe("swap_d2h", time.perf_counter() - t_d2h)
         swap = _SwapState(
             rid=seq.rid, t_arrival=seq.t_arrival, prompt=seq.prompt,
@@ -1171,10 +1174,7 @@ class PagedInstance:
         self.prefill_chunks += 1
         if seq.prefilled:
             tok = jnp.argmax(logits[0, true_c - 1]).astype(jnp.int32)
-            try:
-                tok.copy_to_host_async()
-            except AttributeError:
-                pass
+            tok.copy_to_host_async()
             pf = _PendingFirst(seq, tok, self._tick_id)
             self._pending_first.append(pf)
             seq.pending_first = pf
@@ -1415,10 +1415,7 @@ class PagedInstance:
                 self._dev["tables"], self._dev["lengths"],
                 self._dev["active"], k=k)
             self._dev["next"], self._dev["lengths"] = nxt, ln
-            try:
-                toks.copy_to_host_async()
-            except AttributeError:       # non-jax stand-ins in tests
-                pass
+            toks.copy_to_host_async()
             dispatch_s = time.perf_counter() - t1
             self.decode_dispatches += 1
             for s in act_rows:           # predictive mirrors: decremented at

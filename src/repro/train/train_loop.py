@@ -7,7 +7,6 @@ explicitly and is covered by tests on host devices.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -130,7 +129,6 @@ def make_ddp_train_step(cfg: ModelConfig, opt_cfg: O.AdamWConfig, mesh,
                         compressor: Optional[str] = None) -> Callable:
     """Pure data-parallel step over mesh axis 'data' with an explicit,
     optionally compressed, gradient all-reduce (see train.compression)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.train import compression as C
 
@@ -145,15 +143,13 @@ def make_ddp_train_step(cfg: ModelConfig, opt_cfg: O.AdamWConfig, mesh,
             opt_cfg, grads, state["opt"], state["params"])
         return {"params": new_params, "opt": new_opt}, {"loss": loss, **opt_metrics}
 
-    state_spec = jax.tree.map(lambda _: P(), jax.tree.leaves([0]))  # placeholder
-
     def wrapped(state, batch):
         pspec = jax.tree.map(lambda _: P(), state)
         bspec = jax.tree.map(lambda _: P(axis), batch)
-        f = shard_map(step, mesh=mesh,
-                      in_specs=(pspec, bspec),
-                      out_specs=(pspec, jax.tree.map(lambda _: P(), {"loss": 0, "grad_norm": 0, "lr": 0})),
-                      check_rep=False)
+        f = jax.shard_map(step, mesh=mesh,
+                          in_specs=(pspec, bspec),
+                          out_specs=(pspec, jax.tree.map(lambda _: P(), {"loss": 0, "grad_norm": 0, "lr": 0})),
+                          check_vma=False)
         return jax.jit(f)(state, batch)   # shard_map bodies with named remat
                                           # (checkpoint_name) require jit
 

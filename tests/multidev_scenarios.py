@@ -130,7 +130,7 @@ def scenario_seq_sharded_decode_numerics():
     """Sequence-sharded KV decode == single-device decode logits."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_smoke_config
     from repro.models import registry as R
     from repro.sharding import rules
@@ -140,7 +140,8 @@ def scenario_seq_sharded_decode_numerics():
     toks = jnp.array([[3], [5]], dtype=jnp.int32)
     ref_logits, _ = R.decode_step(params, cache, {"tokens": toks}, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cache_specs = rules.cache_specs(jax.eval_shape(lambda: cache), mesh, cfg,
                                     seq_shard=True)
     with mesh:
@@ -281,6 +282,24 @@ def scenario_disagg_smoke():
     assert m["served"] == len(prompts)
     assert m["handoffs"] == len(prompts)
     check_disagg_conservation(m)
+
+
+def scenario_chip_smoke_four_chips():
+    """``chip_smoke.py --chips 4``'s phases on four of the virtual devices
+    at the smoke widths (KV heads 4, so the model axis of 4 divides them):
+    sharded paged serving, the unsharded engine, disaggregation with token
+    identity, and the sharded-vs-unsharded prefill logit bound."""
+    import jax
+    import jax.numpy as jnp
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    import chip_smoke as CS
+    from repro.configs import get_smoke_config
+
+    cfg = get_smoke_config(CS.ARCH).with_(n_heads=8, n_kv_heads=4,
+                                          dtype=jnp.bfloat16)
+    plan = CS.Plan(prompt_lens=(8, 20, 33, 47), new_tokens=(4, 6, 8, 5),
+                   shared_prefix=16, block_size=8, n_slots=3, max_seqs=4)
+    CS.run_four_chips(cfg, plan, 0, jax.devices()[:4])
 
 
 if __name__ == "__main__":

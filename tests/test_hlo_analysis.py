@@ -56,7 +56,6 @@ def test_collectives_in_scan_counted_with_trip(tmp_path):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch import hlo_analysis as HA
         mesh = jax.make_mesh((8,), ("d",))
@@ -67,7 +66,8 @@ def test_collectives_in_scan_counted_with_trip(tmp_path):
                     return c + 0 * s, s
                 y, ys = jax.lax.scan(body, x, None, length=5)
                 return y + ys.sum(0)
-            return shard_map(inner, mesh=mesh, in_specs=P("d"), out_specs=P("d"))(x)
+            return jax.shard_map(inner, mesh=mesh, in_specs=P("d"),
+                                 out_specs=P("d"))(x)
         c2 = jax.jit(g).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
         st2 = HA.analyze(c2.as_text(), 8)
         expect = 5 * 2 * (7 / 8) * 128 * 4
@@ -80,6 +80,7 @@ def test_collectives_in_scan_counted_with_trip(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src")) + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"       # virtual host devices, never a chip
     out = subprocess.run([sys.executable, str(p)], capture_output=True,
                          text=True, timeout=300, env=env)
     assert out.returncode == 0, out.stderr

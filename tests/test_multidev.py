@@ -13,6 +13,9 @@ _SRC = os.path.abspath(os.path.join(_HERE, "..", "src"))
 def _run(name: str, timeout: int = 600):
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # the scenarios measure on virtual CPU devices by design: pin the
+    # platform so a child never competes with its parent for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, os.path.join(_HERE, "multidev_scenarios.py"), name],
         capture_output=True, text=True, timeout=timeout, env=env)
@@ -50,3 +53,7 @@ def test_disagg_vs_monolithic_parity():
 
 def test_disagg_smoke():
     _run("disagg_smoke")
+
+
+def test_chip_smoke_four_chips():
+    _run("chip_smoke_four_chips")
