@@ -14,7 +14,8 @@ plane they all emit into:
     backend's ``stats()`` is a *view* over its registry;
   * :mod:`repro.obs.trace` — a low-overhead span/event recorder capturing
     each request's arrival → hold/release (with the policy's reason) →
-    admission → prefill chunks → decode ticks (one event per tick with the
+    admission → prefill chunks → decode dispatches (one span per landed
+    dispatch, from its launch to its landing, with its step count and
     occupant set) → preempt/swap → completion, exportable as JSONL and as
     Chrome-trace JSON (load it in Perfetto).  Request spans carry their
     attributed joules/gCO2, so a trace is a visual audit of the carbon
@@ -35,9 +36,11 @@ plane they all emit into:
   * :mod:`repro.obs.slo` — declarative latency-SLO / carbon-budget rules
     evaluated as multi-window error-budget burn rates with deterministic
     fire/clear alert state, consumed by the core controller;
-  * :mod:`repro.obs.profile` — phase timers (prefill chunks, decode
-    dispatch/land, swap D2H/H2D) feeding ``phase``-labeled latency
-    histograms in both engines.
+  * :mod:`repro.obs.profile` — the engines' phase spans (step, admission,
+    tick, prefill chunks, decode dispatch/land, swap D2H/H2D, ...): each
+    opens a device-profiler annotation ``engine.<phase>`` through a factory
+    the engine hands in, and feeds ``phase``-labeled latency histograms.
+    Those latencies are host enqueue or wait times, not device time.
 
 The package is deliberately jax-free (stdlib + numpy only): the DES/fluid
 paths and ``scripts/check.sh``'s trace-validation step must run without

@@ -2,8 +2,9 @@
 
 One :class:`TraceRecorder` captures a serving backend's whole request
 lifecycle — arrival → hold/release (policy decision + reason) → admission →
-chunked-prefill chunks → decode ticks (batched: ONE event per tick carrying
-the occupant set) → preempt / swap-out / partial swap-in → completion — and
+chunked-prefill chunks → decode dispatches (batched: ONE span per landed
+dispatch, from launch to landing, carrying its step count ``k`` and the
+occupant set) → preempt / swap-out / partial swap-in → completion — and
 exports it two ways:
 
   * **JSONL** (:meth:`TraceRecorder.to_jsonl`): one record per line, the

@@ -112,6 +112,12 @@ class InferenceResponse:
     t_finish: float = 0.0
     queue_delay_s: float = 0.0         # arrival → first admission
     ttft_s: float = 0.0                # arrival → first generated token
+    # the part of ttft_s after admission, split where the work happens:
+    # admission → dispatch of the last prefill chunk, then → the first
+    # token's value on the host; queue_delay_s + prefill_wait_s +
+    # first_lag_s == ttft_s on the real engine
+    prefill_wait_s: float = 0.0
+    first_lag_s: float = 0.0
     latency_s: float = 0.0             # arrival → completion
     energy_j: float = 0.0
     carbon_g: float = 0.0

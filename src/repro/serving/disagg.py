@@ -108,7 +108,8 @@ class DisaggEngine(RealEngine):
         self.roles = {r: int(n) for r, n in roles.items()}
         # per-role phase profilers: the same PHASES catalog, labeled by
         # role, so phase latency splits prefill-pool vs decode-pool
-        self.profilers = {r: PhaseProfiler(role=r)
+        self.profilers = {r: PhaseProfiler(role=r,
+                                           annotate=self.profiler.annotate)
                           for r in ("prefill", "decode")}
         self._handoffq: Deque[BlockHandoff] = deque()
 

@@ -141,10 +141,16 @@ def _variant_fns(ev: EngineVariant) -> dict:
     EngineVariant (jax's jit cache then handles per-shape specialisation)."""
     if "prefill" not in ev.fns:
         cfg = ev.cfg
-        ev.fns["prefill"] = jax.jit(
-            lambda p, t: R.prefill_kv(p, {"tokens": t}, cfg))
-        ev.fns["decode"] = jax.jit(
-            lambda p, c, t, a: R.decode_slots(p, c, {"tokens": t}, cfg, a))
+
+        # named functions, so the XLA modules are ``jit_prefill`` etc.
+        def prefill(p, t):
+            return R.prefill_kv(p, {"tokens": t}, cfg)
+
+        def decode(p, c, t, a):
+            return R.decode_slots(p, c, {"tokens": t}, cfg, a)
+
+        ev.fns["prefill"] = jax.jit(prefill)
+        ev.fns["decode"] = jax.jit(decode)
         ev.fns["write"] = jax.jit(_write_slot)
     return ev.fns
 
@@ -166,27 +172,47 @@ def _paged_fns(ev: EngineVariant) -> dict:
     ``gather_pages`` are the swap staging pair: a donated in-place page
     scatter for swap-in (the un-jitted ``.at[].set`` copied the whole
     arena) and a page gather whose result is copied device→host
-    asynchronously at swap-out."""
+    asynchronously at swap-out.  ``first_token`` (a final chunk's
+    last-position argmax) and ``set_next`` (its scatter into an uploaded
+    ``next`` buffer) are the small steps between them.
+
+    Every entry is a named function, so each XLA module carries its name
+    (``jit_prefill_paged``, ``jit_decode_multi``, ...) into the device
+    trace."""
     if "prefill_paged" not in ev.fns:
         cfg = ev.cfg
-        ev.fns["prefill_paged"] = jax.jit(
-            lambda p, t, ar, tb, np_, tc: R.prefill_paged(
-                p, {"tokens": t}, cfg, ar, tb, np_, tc),
-            donate_argnums=(2,))
-        ev.fns["decode_paged"] = jax.jit(
-            lambda p, ar, t, tb, ln, act: R.decode_paged(
-                p, ar, {"tokens": t}, cfg, tb, ln, act),
-            donate_argnums=(1,))
+
+        def prefill_paged(p, t, ar, tb, np_, tc):
+            return R.prefill_paged(p, {"tokens": t}, cfg, ar, tb, np_, tc)
+
+        def decode_paged(p, ar, t, tb, ln, act):
+            return R.decode_paged(p, ar, {"tokens": t}, cfg, tb, ln, act)
+
+        def decode_multi(p, ar, t, tb, ln, act, k):
+            return R.decode_paged_multi(p, ar, {"tokens": t}, cfg, tb, ln,
+                                        act, k)
+
+        def restore_paged(ar, idx, hk, hv):
+            return {"k": ar["k"].at[:, idx].set(hk),
+                    "v": ar["v"].at[:, idx].set(hv)}
+
+        def gather_pages(ar, idx):
+            return ar["k"][:, idx], ar["v"][:, idx]
+
+        def first_token(logits, i):
+            return jnp.argmax(logits[0, i]).astype(jnp.int32)
+
+        def set_next(nxt, row, tok):
+            return nxt.at[row, 0].set(tok)
+
+        ev.fns["prefill_paged"] = jax.jit(prefill_paged, donate_argnums=(2,))
+        ev.fns["decode_paged"] = jax.jit(decode_paged, donate_argnums=(1,))
         ev.fns["decode_multi"] = jax.jit(
-            lambda p, ar, t, tb, ln, act, k: R.decode_paged_multi(
-                p, ar, {"tokens": t}, cfg, tb, ln, act, k),
-            static_argnames=("k",), donate_argnums=(1, 2, 4))
-        ev.fns["restore_paged"] = jax.jit(
-            lambda ar, idx, hk, hv: {"k": ar["k"].at[:, idx].set(hk),
-                                     "v": ar["v"].at[:, idx].set(hv)},
-            donate_argnums=(0,))
-        ev.fns["gather_pages"] = jax.jit(
-            lambda ar, idx: (ar["k"][:, idx], ar["v"][:, idx]))
+            decode_multi, static_argnames=("k",), donate_argnums=(1, 2, 4))
+        ev.fns["restore_paged"] = jax.jit(restore_paged, donate_argnums=(0,))
+        ev.fns["gather_pages"] = jax.jit(gather_pages)
+        ev.fns["first_token"] = jax.jit(first_token)
+        ev.fns["set_next"] = jax.jit(set_next)
     return ev.fns
 
 
@@ -260,7 +286,8 @@ class _SlotState:
     t_arrival: float
     remaining: int                 # decode steps still to run
     tokens: List[int]              # generated token ids (prefill token first)
-    t_first: Optional[float] = None   # wall time of the first generated token
+    t_first: Optional[float] = None   # host time the first token was read
+    t_prefilled: Optional[float] = None   # host time prefill was dispatched
     priority: int = 0
     preempts: int = 0              # slotted sequences never preempt (uniform
                                    # field so the engine reads one shape)
@@ -299,6 +326,7 @@ class _SwapState:
     n_ctx: int                     # K/V positions already in the arena
     next_token: int
     t_first: Optional[float]
+    t_prefilled: Optional[float]
     cached_tokens: int
     preempts: int
     img_k: object                  # (L, >=n_blocks, bs, K, dh) device or np
@@ -339,7 +367,7 @@ class _PendingDecode:
     toks: object                          # (k, B) i32 device array
     rows: List[Tuple["_PagedSeq", int]]   # (seq, dispatch-time row)
     k: int
-    occupied: int
+    t_dispatch: float                     # host stamps of the launch
     dispatch_s: float
     tick_id: int
 
@@ -350,22 +378,23 @@ class _PendingFirst:
     chunk's last-position argmax with an async host copy in flight.  The
     device scalar is scattered into the uploaded ``next`` buffer whenever
     loop state is pushed (so decode never waits on its value); the host
-    only reads it to record ``seq.tokens[0]`` — one tick later, or
-    immediately (a counted sync) when the request is n_new == 1."""
+    only reads it to record ``seq.tokens[0]`` — one tick later, after the
+    decode calls dispatched before that tick, or immediately (a counted
+    sync) when the request is n_new == 1."""
     seq: "_PagedSeq"
     tok: object                           # () i32 device array
     tick_id: int
 
 
 def _tick_info(prefill_s: float = 0.0, decode_s: float = 0.0,
-               decode_steps: int = 0, occupied: int = 0,
-               blocks_in_use: int = 0, prefill_rids=None, decode_rids=None,
+               blocks_in_use: int = 0, prefill_rids=None, decodes=None,
                emitted=None, preempted=None) -> Dict[str, object]:
     return {"prefill_s": prefill_s, "decode_s": decode_s,
-            "decode_steps": decode_steps, "occupied": occupied,
             "blocks_in_use": blocks_in_use,
-            "prefill_rids": prefill_rids or [],   # [(rid, seconds), ...]
-            "decode_rids": decode_rids or [],     # rows sharing the decode
+            "prefill_rids": prefill_rids or [],   # [(rid, t0, t1), ...]
+            # one per landed dispatch: (t_launch, t_landed, k, rids); its
+            # rids are the rows that shared it
+            "decodes": decodes or [],
             "emitted": emitted or [],             # [(rid, token), ...]
             "preempted": preempted or []}         # [_SwapState, ...]
 
@@ -381,10 +410,10 @@ def _note_shape(inst, key: Tuple) -> None:
         inst.retraces += 1
 
 
-# disabled-by-default phase profiler: instances constructed outside a
-# RealEngine observe into this shared no-op (registry=None) shim; the
-# engine overrides ``inst.profiler`` with its own at configure()
-_NULL_PROFILER = PhaseProfiler()
+# phase profiler of instances constructed outside a RealEngine: it
+# annotates the device trace but observes no histogram (registry=None);
+# the engine overrides ``inst.profiler`` with its own at configure()
+_NULL_PROFILER = PhaseProfiler(annotate=jax.profiler.TraceAnnotation)
 
 
 class Instance:
@@ -485,18 +514,19 @@ class Instance:
         slotted layout prefills at admission, so there is no prefill queue
         for a policy to order."""
         slot = self.free_slots()[0]
-        t1 = time.perf_counter()
-        state = self.admit(slot, rid, t_arrival, prompt, n_new,
-                           priority=priority)
-        state.t_first = time.perf_counter()
-        return state, state.t_first - t1
+        with self.profiler.span("prefill_chunk", rid=rid) as sp:
+            state = self.admit(slot, rid, t_arrival, prompt, n_new,
+                               priority=priority)
+        return state, sp.seconds
 
     def admit(self, slot: int, rid: int, t_arrival: float,
               prompt: np.ndarray, n_new: int, priority: int = 0
               ) -> _SlotState:
         """One-pass prefill of ``prompt`` into ``slot``.  The prompt's
         last-position logits yield the first generated token immediately —
-        the prefill forward is never discarded."""
+        the prefill forward is never discarded.  The state is stamped when
+        the prefill is dispatched (``t_prefilled``) and when the first
+        token's value reaches the host (``t_first``)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         true_len = int(prompt.shape[0])
         assert true_len + n_new <= self.max_len, \
@@ -514,9 +544,11 @@ class Instance:
                                self.cache["lengths"], k_all[:, :, :write],
                                v_all[:, :, :write], slot, true_len)
         self.host_syncs += 1             # blocking first-token readback
+        t_prefilled = time.perf_counter()
         first = int(jnp.argmax(logits[0, true_len - 1]))
         state = _SlotState(rid, t_arrival, remaining=n_new - 1,
-                           tokens=[first], priority=priority)
+                           tokens=[first], t_first=time.perf_counter(),
+                           t_prefilled=t_prefilled, priority=priority)
         self._next[slot, 0] = first
         if state.remaining > 0:
             self.slots[slot] = state
@@ -529,16 +561,15 @@ class Instance:
         active = np.array([s is not None for s in self.slots])
         _note_shape(self, ("decode",))
         self.h2d_transfers += 2          # next-token + active-mask uploads
-        t_d0 = time.perf_counter()
-        logits, self.cache = self._fns["decode"](
-            self.ev.params, self.cache, jnp.asarray(self._next),
-            jnp.asarray(active))
+        with self.profiler.span("decode_dispatch", k=1,
+                                rows=int(active.sum())):
+            logits, self.cache = self._fns["decode"](
+                self.ev.params, self.cache, jnp.asarray(self._next),
+                jnp.asarray(active))
         self.host_syncs += 1             # blocking per-step token readback
         self.decode_dispatches += 1
-        t_l0 = time.perf_counter()
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
-        self.profiler.observe("decode_dispatch", t_l0 - t_d0)
-        self.profiler.observe("decode_land", time.perf_counter() - t_l0)
+        with self.profiler.span("decode_land", sync=True):
+            toks = np.asarray(jnp.argmax(logits, axis=-1))
         finished: List[_SlotState] = []
         emitted: List[Tuple[int, int]] = []
         for i, s in enumerate(self.slots):
@@ -564,9 +595,10 @@ class Instance:
         rids = [s.rid for s in self.slots if s is not None]
         t1 = time.perf_counter()
         finished, emitted = self.step()
-        dt = time.perf_counter() - t1
-        return finished, _tick_info(decode_s=dt, decode_steps=1, occupied=occ,
-                                    decode_rids=rids, emitted=emitted)
+        t2 = time.perf_counter()
+        return finished, _tick_info(decode_s=t2 - t1,
+                                    decodes=[(t1, t2, 1, rids)],
+                                    emitted=emitted)
 
     def generate(self, prompt: np.ndarray, n_new: int = 8
                  ) -> Tuple[np.ndarray, float]:
@@ -627,7 +659,8 @@ class _PagedSeq:
     cached_tokens: int              # prefix-cache hit size at admission
     remaining: int = 0
     tokens: List[int] = dataclasses.field(default_factory=list)
-    t_first: Optional[float] = None
+    t_prefilled: Optional[float] = None   # last prefill chunk dispatched
+    t_first: Optional[float] = None       # first token's value on the host
     priority: int = 0
     preempts: int = 0               # times this sequence was swapped out
     pending_steps: int = 0          # decode steps dispatched but not landed
@@ -759,9 +792,7 @@ class PagedInstance:
         self._ev_emitted: List[Tuple[int, int]] = []
         self._ev_finished: List[_PagedSeq] = []
         self._ld_s = 0.0                 # landed decode seconds this tick
-        self._ld_steps = 0
-        self._ld_occ = 0
-        self._ld_rids: List[int] = []
+        self._ld_decodes: List[Tuple[float, float, int, List[int]]] = []
         # host↔device traffic + dispatch counters (lifetime; session deltas)
         self.host_syncs = 0
         self.h2d_transfers = 0
@@ -804,7 +835,8 @@ class PagedInstance:
         fixed-size prefill chunk plus, per power-of-two row bucket
         (``_row_buckets`` — the batch-axis analogue of ``serve_buckets``),
         the fused decode at both step counts the loop dispatches (k = 1
-        pipelined single-step, k = ``fused_steps`` when eligible).
+        pipelined single-step, k = ``fused_steps`` when eligible), and the
+        first-token argmax with its scatter into each bucket's ``next``.
         ``true_c = 0`` / an all-False mask route every warmup write to the
         junk block, so logical state is untouched."""
         dummy = jnp.zeros((1, self.chunk_tokens), jnp.int32)
@@ -814,8 +846,11 @@ class PagedInstance:
                 self.params, dummy, self.arena,
                 jnp.zeros((span,), jnp.int32), 0, 0)
             lg.block_until_ready()
+        tok = self._fns["first_token"](lg, 0)
         ks = sorted({1, self.fused_steps})
         for B in self._row_buckets():
+            self._fns["set_next"](self._put_rows(self._next[:B]), 0,
+                                  tok).block_until_ready()
             for k in ks:
                 self._shapes.add(("decode_multi", B, k))
                 toks, self.arena, _, _ = self._fns["decode_multi"](
@@ -953,11 +988,10 @@ class PagedInstance:
                 hk = np.pad(hk, pad)
                 hv = np.pad(hv, pad)
             self.h2d_transfers += 3      # index vector + K + V page uploads
-            t_h2d = time.perf_counter()
-            self.arena = self._fns["restore_paged"](
-                self.arena, jnp.asarray(idx), jnp.asarray(hk),
-                jnp.asarray(hv))
-            self.profiler.observe("swap_h2d", time.perf_counter() - t_h2d)
+            with self.profiler.span("swap_h2d"):
+                self.arena = self._fns["restore_paged"](
+                    self.arena, jnp.asarray(idx), jnp.asarray(hk),
+                    jnp.asarray(hv))
         blocks = reused + tail
         self.swapin_pages_total += nb
         self.swapin_pages_copied += n_tail
@@ -965,7 +999,8 @@ class PagedInstance:
                         row, blocks, n_done=len(swap.prompt),
                         cached_tokens=swap.cached_tokens,
                         remaining=swap.remaining, tokens=list(swap.tokens),
-                        t_first=swap.t_first, priority=swap.priority,
+                        t_prefilled=swap.t_prefilled, t_first=swap.t_first,
+                        priority=swap.priority,
                         preempts=swap.preempts, slo=swap.slo,
                         deadline_s=swap.deadline_s, seq=self._adm_seq)
         self._adm_seq += 1
@@ -1036,17 +1071,17 @@ class PagedInstance:
         tree_blocks = 0
         if self.prefix is not None:
             tree_blocks = self.prefix.live_prefix_blocks(seq.prompt, limit=nb)
-        t_d2h = time.perf_counter()
-        img_k, img_v = self._fns["gather_pages"](self.arena, jnp.asarray(idx))
-        img_k.copy_to_host_async()
-        img_v.copy_to_host_async()
-        self.profiler.observe("swap_d2h", time.perf_counter() - t_d2h)
+        with self.profiler.span("swap_d2h"):
+            img_k, img_v = self._fns["gather_pages"](self.arena,
+                                                     jnp.asarray(idx))
+            img_k.copy_to_host_async()
+            img_v.copy_to_host_async()
         swap = _SwapState(
             rid=seq.rid, t_arrival=seq.t_arrival, prompt=seq.prompt,
             n_new=seq.n_new, priority=seq.priority, tokens=list(seq.tokens),
             remaining=seq.remaining, n_ctx=n_ctx,
             next_token=int(self._next[seq.row, 0]), t_first=seq.t_first,
-            cached_tokens=seq.cached_tokens,
+            t_prefilled=seq.t_prefilled, cached_tokens=seq.cached_tokens,
             preempts=seq.preempts + (1 if count_preempt else 0),
             img_k=img_k, img_v=img_v, nb=nb,
             tree_blocks=tree_blocks, slo=seq.slo, deadline_s=seq.deadline_s)
@@ -1156,7 +1191,8 @@ class PagedInstance:
         async host copy) instead of the old blocking ``int(jnp.argmax(...))``
         per final chunk, and the pending device scalar is scattered into the
         ``next`` buffer at the following upload — the host records its value
-        through the pipelined landing path (``_land_first``)."""
+        through the pipelined landing path (``_land_first``), which stamps
+        ``t_first``; the final chunk's dispatch stamps ``t_prefilled``."""
         start = seq.n_done
         true_c = min(self.chunk_tokens, len(seq.prompt) - start)
         padded = np.zeros((1, self.chunk_tokens), np.int32)
@@ -1173,13 +1209,13 @@ class PagedInstance:
         seq.n_done += true_c
         self.prefill_chunks += 1
         if seq.prefilled:
-            tok = jnp.argmax(logits[0, true_c - 1]).astype(jnp.int32)
+            tok = self._fns["first_token"](logits, true_c - 1)
             tok.copy_to_host_async()
+            seq.t_prefilled = time.perf_counter()
             pf = _PendingFirst(seq, tok, self._tick_id)
             self._pending_first.append(pf)
             seq.pending_first = pf
             seq.remaining -= 1
-            seq.t_first = time.perf_counter()
             self.lengths[seq.row] = len(seq.prompt)
             self._dirty = True           # row activation: mirrors changed
             if self.prefix is not None:
@@ -1187,20 +1223,24 @@ class PagedInstance:
 
     # --- pipelined landing ----------------------------------------------------
     def _land_first(self, pf: _PendingFirst) -> None:
-        """Record a pending first token on the host.  Landing in the tick
-        that created it is a forced (blocking) round-trip and counts as a
+        """Record a pending first token on the host and stamp ``t_first``:
+        the moment its value is known here.  Landing in the tick that
+        created it is a forced (blocking) round-trip and counts as a
         ``host_syncs``; landing later overlapped host work for free."""
         seq = pf.seq
-        if pf.tick_id == self._tick_id:
+        sync = pf.tick_id == self._tick_id
+        if sync:
             self.host_syncs += 1
-        first = int(np.asarray(pf.tok))
-        seq.tokens.append(first)         # tokens[0]: decode landings wait
-        self._ev_emitted.append((seq.rid, first))
-        seq.pending_first = None
-        if pf in self._pending_first:
-            self._pending_first.remove(pf)
-        if self.rows[seq.row] is seq:
-            self._next[seq.row, 0] = first
+        with self.profiler.span("decode_land", sync=sync):
+            first = int(np.asarray(pf.tok))
+            seq.t_first = time.perf_counter()
+            seq.tokens.append(first)     # tokens[0]: decode landings wait
+            self._ev_emitted.append((seq.rid, first))
+            seq.pending_first = None
+            if pf in self._pending_first:
+                self._pending_first.remove(pf)
+            if self.rows[seq.row] is seq:
+                self._next[seq.row, 0] = first
 
     def _land_item(self, item: _PendingDecode) -> None:
         """Land one dispatched decode call: block on its (k, B) token
@@ -1208,21 +1248,18 @@ class PagedInstance:
         accumulators, and complete sequences whose final tokens arrived.
         A sequence's pending first token (if any) lands first — per-request
         token order is part of the greedy-parity contract."""
-        if item.tick_id == self._tick_id:
+        sync = item.tick_id == self._tick_id
+        if sync:
             self.host_syncs += 1         # same-tick landing: no overlap
-        t0 = time.perf_counter()
-        toks = np.asarray(item.toks)     # blocks until the async copy lands
-        t_land = time.perf_counter() - t0
-        self._ld_s += item.dispatch_s + t_land
-        self.profiler.observe("decode_dispatch", item.dispatch_s)
-        self.profiler.observe("decode_land", t_land)
-        self._ld_steps += item.k
-        self._ld_occ = max(self._ld_occ, item.occupied)
+        with self.profiler.span("decode_land", sync=sync) as sp:
+            toks = np.asarray(item.toks)  # blocks until the async copy lands
+        self._ld_s += item.dispatch_s + sp.seconds
+        self._ld_decodes.append((item.t_dispatch, sp.t1, item.k,
+                                 [s.rid for s, _ in item.rows]))
         done: List[_PagedSeq] = []
         for s, col in item.rows:
             if s.pending_first is not None:
                 self._land_first(s.pending_first)
-            self._ld_rids.append(s.rid)
             for i in range(item.k):
                 t = int(toks[i, col])
                 s.tokens.append(t)
@@ -1238,12 +1275,15 @@ class PagedInstance:
     def _land_ready(self) -> None:
         """Collect readbacks dispatched BEFORE this tick — their async
         copies overlapped at least one full tick of host work, so these
-        landings are free (no ``host_syncs``)."""
+        landings are free (no ``host_syncs``).  Decode calls land first:
+        the tick emits nothing to clients before its last landing, so a
+        first token read ahead of them would be stamped (``t_first``)
+        before the wait that still holds it back from its client."""
+        while self._inflight and self._inflight[0].tick_id < self._tick_id:
+            self._land_item(self._inflight.popleft())
         for pf in list(self._pending_first):
             if pf.tick_id < self._tick_id:
                 self._land_first(pf)
-        while self._inflight and self._inflight[0].tick_id < self._tick_id:
-            self._land_item(self._inflight.popleft())
 
     def _flush_decodes(self) -> None:
         """Force-land every in-flight decode call (upload precondition:
@@ -1269,7 +1309,7 @@ class PagedInstance:
         assert not self._inflight, "upload with stale in-flight decodes"
         nxt = self._put_rows(self._next[:B])
         for pf in self._pending_first:
-            nxt = nxt.at[pf.seq.row, 0].set(pf.tok)
+            nxt = self._fns["set_next"](nxt, pf.seq.row, pf.tok)
         self._dev = {"next": nxt,
                      "tables": self._put_rows(self.tables[:B]),
                      "lengths": self._put_rows(self.lengths[:B]),
@@ -1343,10 +1383,8 @@ class PagedInstance:
         self._ev_emitted = []
         self._ev_finished = []
         self._ld_s = 0.0
-        self._ld_steps = 0
-        self._ld_occ = 0
-        self._ld_rids = []
-        prefill_rids: List[Tuple[int, float]] = []
+        self._ld_decodes = []
+        prefill_rids: List[Tuple[int, float, float]] = []
         prefill_s = 0.0
         if self._prefillq:
             burst = 0
@@ -1358,11 +1396,10 @@ class PagedInstance:
                     break                        # decode is busy: yield
                 qi = self._next_prefill(now)
                 seq = self._prefillq[qi]
-                tc = time.perf_counter()
-                self._prefill_chunk(seq)
-                dtc = time.perf_counter() - tc
-                prefill_rids.append((seq.rid, dtc))
-                prefill_s += dtc
+                with self.profiler.span("prefill_chunk", rid=seq.rid) as sp:
+                    self._prefill_chunk(seq)
+                prefill_rids.append((seq.rid, sp.t0, sp.t1))
+                prefill_s += sp.seconds
                 burst += 1
                 if seq.prefilled:
                     del self._prefillq[qi]
@@ -1397,33 +1434,34 @@ class PagedInstance:
                     or not np.array_equal(active[:B], self._dev_active)):
                 # EVENT path: land in-flight work (mirrors must equal the
                 # device state), then push fresh loop state once
-                self._flush_decodes()    # may release rows -> recompute
-                active = np.array([s is not None and s.prefilled
-                                   and s.remaining > 0 for s in self.rows])
-                occ = int(active.sum())
-                if occ:
-                    B = _pow2_bucket(self.occupied, self.max_seqs)
-                    self._upload(B, active)
+                with self.profiler.span("upload"):
+                    self._flush_decodes()    # may release rows -> recompute
+                    active = np.array([s is not None and s.prefilled
+                                       and s.remaining > 0
+                                       for s in self.rows])
+                    occ = int(active.sum())
+                    if occ:
+                        B = _pow2_bucket(self.occupied, self.max_seqs)
+                        self._upload(B, active)
         if occ:
             act_rows = [s for s in self.rows[:B]
                         if s is not None and s.prefilled and s.remaining > 0]
             k = self._choose_k(act_rows) if allow_fused else 1
             _note_shape(self, ("decode_multi", B, k))
-            t1 = time.perf_counter()
-            toks, self.arena, nxt, ln = self._fns["decode_multi"](
-                self.params, self.arena, self._dev["next"],
-                self._dev["tables"], self._dev["lengths"],
-                self._dev["active"], k=k)
-            self._dev["next"], self._dev["lengths"] = nxt, ln
-            toks.copy_to_host_async()
-            dispatch_s = time.perf_counter() - t1
+            with self.profiler.span("decode_dispatch", k=k, rows=occ) as sp:
+                toks, self.arena, nxt, ln = self._fns["decode_multi"](
+                    self.params, self.arena, self._dev["next"],
+                    self._dev["tables"], self._dev["lengths"],
+                    self._dev["active"], k=k)
+                self._dev["next"], self._dev["lengths"] = nxt, ln
+                toks.copy_to_host_async()
             self.decode_dispatches += 1
             for s in act_rows:           # predictive mirrors: decremented at
                 s.remaining -= k         # dispatch; truth lands later
                 s.pending_steps += k
                 self.lengths[s.row] += k
             self._inflight.append(_PendingDecode(
-                toks, [(s, s.row) for s in act_rows], k, occ, dispatch_s,
+                toks, [(s, s.row) for s in act_rows], k, sp.t0, sp.seconds,
                 self._tick_id))
         # LANDING: readbacks dispatched before this tick overlapped a full
         # tick of host work — collect them for free; the synchronous
@@ -1434,10 +1472,10 @@ class PagedInstance:
             self._flush_all()
         return self._ev_finished, _tick_info(
             prefill_s=prefill_s, decode_s=self._ld_s,
-            decode_steps=self._ld_steps, occupied=self._ld_occ,
             blocks_in_use=self.alloc.blocks_in_use(),
-            prefill_rids=prefill_rids, decode_rids=self._ld_rids,
-            emitted=self._ev_emitted, preempted=preempted)
+            prefill_rids=prefill_rids, decodes=self._ld_decodes,
+            emitted=self._ev_emitted,
+            preempted=preempted)
 
 
 # =============================================================================
@@ -1470,6 +1508,8 @@ class _Session:
         self.admit_order: List[int] = []
         self.queue_delays: List[float] = []
         self.ttfts: List[float] = []
+        self.prefill_waits: List[float] = []
+        self.first_lags: List[float] = []
         self.energy = 0.0
         # per-role joules (disaggregation accounting): every charge is
         # tagged with the instance's role — "both" for monolithic engines,
@@ -1606,11 +1646,12 @@ class RealEngine:
         # emits lifecycle spans into its persistent ``tracer``; its ``feed``
         # receives one exact (wall, joules, grams) segment per session
         self.telemetry = telemetry
-        # one engine-owned phase profiler shared by every instance; its
-        # registry is repointed at each session open (and set to None when
-        # no telemetry bundle is attached, so the un-instrumented hot path
-        # stays a single attribute check)
-        self.profiler = PhaseProfiler()
+        # one engine-owned phase profiler shared by every instance: its
+        # spans annotate the device profiler's timeline (nothing is
+        # recorded while no profiler session runs); its registry is
+        # repointed at each session open (and set to None when no telemetry
+        # bundle is attached, so no histogram sample is taken)
+        self.profiler = PhaseProfiler(annotate=jax.profiler.TraceAnnotation)
         self.last_registry: Optional[MetricsRegistry] = None
         self._feed_clock = 0.0           # feed-time seconds across sessions
         self._pool: Dict[Tuple[str, int], List[Instance]] = {}
@@ -1733,20 +1774,33 @@ class RealEngine:
         s = self._session
         if s is None:
             return []
+        with self.profiler.span("step"):
+            s.progressed = False
+            completed: List[InferenceResponse] = []
+            with self.profiler.span("admit"):
+                self._admit(s, completed)
+            for inst in self.instances:
+                if inst.busy:
+                    with inst.profiler.span("tick"):
+                        self._tick(s, inst, completed)
+            self._post_tick(completed)
+        return completed
+
+    def _admit(self, s: _Session, completed: List[InferenceResponse]
+               ) -> None:
+        """Release due arrivals, then admission: peek the policy's next
+        choice and place it on the first instance with capacity (slots or
+        blocks) — mid-flight, so rows/blocks freed by the previous tick's
+        completions refill.  A failed fit is GATED per instance: no
+        re-attempt until the queue head or the instance's free capacity
+        actually changes."""
         now = time.perf_counter()
         now_rel = s.rel(now)
-        s.progressed = False
-        completed: List[InferenceResponse] = []
         while s.future and s.future[0][0] <= now:
             t_arr, _, rid = heapq.heappop(s.future)
             req = s.requests[rid]
             s.core.submit(rid, t_arr, priority=req.priority,
                           deadline_s=req.deadline_s, slo=req.slo)
-        # 1. admission: peek the policy's next choice and place it on the
-        #    first instance with capacity (slots or blocks) — mid-flight, so
-        #    rows/blocks freed by the previous tick's completions refill.
-        #    A failed fit is GATED per instance: no re-attempt until the
-        #    queue head or the instance's free capacity actually changes.
         for inst in self.instances:
             while True:
                 nxt = s.core.peek_next(now_rel)
@@ -1802,8 +1856,6 @@ class RealEngine:
                         req.on_token(rid, state.tokens[0])   # slotted first
                     if s.tracer is not None:
                         s.tracer.instant("admit", s.rel(t1), rid=rid)
-                if dt > 0:               # slotted layout prefills at admit
-                    self.profiler.observe("prefill_chunk", dt)
                 e_pf = inst.chips * PM.P_BUSY_W * dt   # prefill: busy power
                 s.charge(inst.role, e_pf)
                 s.meter(rid, inst.role, e_pf)
@@ -1811,97 +1863,96 @@ class RealEngine:
                 s.progressed = True
                 if state.remaining <= 0 and state.tokens:    # n_new == 1
                     completed.append(self._finish(state, inst))
-        # 2. one tick per busy instance (≤ 1 prefill burst + 1 decode)
-        for inst in self.instances:
-            if not inst.busy:
-                continue
-            s.progressed = True
-            s.admitted_sum += inst.occupied   # holding cache memory now
-            s.tick_samples += 1
-            t_tick = time.perf_counter()
-            # fused multi-step dispatch stays off while timed arrivals are
-            # outstanding: an open-loop session measures admission latency,
-            # and a k-step device window would delay a mid-window arrival's
-            # prefill behind k queued decode steps
-            done, info = inst.tick(s.rel(t_tick), allow_fused=not s.future)
-            s.charge(inst.role, inst.chips * PM.P_BUSY_W * info["prefill_s"])
-            for rid, dtc in info["prefill_rids"]:
-                s.meter(rid, inst.role, inst.chips * PM.P_BUSY_W * dtc)
-                inst.profiler.observe("prefill_chunk", dtc)
-            if info["decode_steps"]:
-                # info describes LANDED decode work: ``decode_steps`` model
-                # steps (>= 1 per landed dispatch, k per fused dispatch)
-                # sharing ``decode_s`` wall seconds — aggregates stay
-                # step-weighted so occupancy/inflight means are comparable
-                # across fused and single-step sessions
-                ksteps = info["decode_steps"]
-                occ = info["occupied"]
-                e_dec = PM.instance_power_w(
-                    inst.chips, occ / inst.capacity) * info["decode_s"]
-                s.charge(inst.role, e_dec)
-                share = e_dec / max(len(info["decode_rids"]), 1)
-                for rid in info["decode_rids"]:
-                    s.meter(rid, inst.role, share)
-                s.decode_steps += ksteps
-                s.occ_frac_sum += (occ / inst.capacity) * ksteps
-                s.inflight_sum += occ * ksteps
-            s.accounted_s[id(inst)] += info["prefill_s"] + info["decode_s"]
-            s.blocks_peak = max(s.blocks_peak, int(info["blocks_in_use"]))
-            s.registry.gauge("occupied_rows").set(info["occupied"])
-            s.registry.gauge("blocks_in_use").set(info["blocks_in_use"])
+
+    def _tick(self, s: _Session, inst,
+              completed: List[InferenceResponse]) -> None:
+        """One tick of a busy instance (≤ 1 prefill burst + 1 decode), then
+        its accounting, lifecycle trace, token callbacks, preemptions and
+        completions."""
+        prof = inst.profiler
+        s.progressed = True
+        s.admitted_sum += inst.occupied   # holding cache memory now
+        s.tick_samples += 1
+        # fused multi-step dispatch stays off while timed arrivals are
+        # outstanding: an open-loop session measures admission latency, and
+        # a k-step device window would delay a mid-window arrival's prefill
+        # behind k queued decode steps
+        done, info = inst.tick(s.rel(time.perf_counter()),
+                               allow_fused=not s.future)
+        s.charge(inst.role, inst.chips * PM.P_BUSY_W * info["prefill_s"])
+        for rid, t0, t1 in info["prefill_rids"]:
+            s.meter(rid, inst.role, inst.chips * PM.P_BUSY_W * (t1 - t0))
+        decodes = info["decodes"]
+        ksteps = sum(k for _, _, k, _ in decodes)
+        occ = max((len(rids) for _, _, _, rids in decodes), default=0)
+        if ksteps:
+            # info describes LANDED decode work: ``ksteps`` model steps
+            # (>= 1 per landed dispatch, k per fused dispatch) sharing
+            # ``decode_s`` wall seconds — aggregates stay step-weighted so
+            # occupancy/inflight means are comparable across fused and
+            # single-step sessions
+            e_dec = PM.instance_power_w(
+                inst.chips, occ / inst.capacity) * info["decode_s"]
+            s.charge(inst.role, e_dec)
+            rows = [rid for _, _, _, rids in decodes for rid in rids]
+            share = e_dec / max(len(rows), 1)
+            for rid in rows:
+                s.meter(rid, inst.role, share)
+            s.decode_steps += ksteps
+            s.occ_frac_sum += (occ / inst.capacity) * ksteps
+            s.inflight_sum += occ * ksteps
+        s.accounted_s[id(inst)] += info["prefill_s"] + info["decode_s"]
+        s.blocks_peak = max(s.blocks_peak, int(info["blocks_in_use"]))
+        s.registry.gauge("occupied_rows").set(occ)
+        s.registry.gauge("blocks_in_use").set(info["blocks_in_use"])
+        if s.tracer is not None:
+            tr = s.tracer
+            # the lifecycle spans take the phase spans' own host stamps: a
+            # chunk from its prep to its dispatch, a decode from its launch
+            # to its landing (one span per landed dispatch, carrying its k)
+            for rid, t0, t1 in info["prefill_rids"]:
+                tr.span("prefill_chunk", s.rel(t0), s.rel(t1), rid=rid)
+            for t0, t1, k, rids in decodes:
+                tr.span("decode", s.rel(t0), s.rel(t1), k=k, rids=rids,
+                        n=len(rids))
+            # memory/power pressure counter tracks on the engine track
+            # (Perfetto renders them alongside the request spans): the
+            # arena/slot occupancy plus this tick's instantaneous power
+            # draw under the same model that charges the energy
+            if ksteps:
+                p_w = PM.instance_power_w(inst.chips, occ / inst.capacity)
+            elif info["prefill_s"] > 0:
+                p_w = inst.chips * PM.P_BUSY_W
+            else:
+                p_w = inst.chips * PM.P_IDLE_W
+            t_end = s.rel(time.perf_counter())
+            tr.counter("blocks_in_use", t_end, info["blocks_in_use"])
+            tr.counter("occupied_rows", t_end, occ)
+            tr.counter("power_w", t_end, p_w)
+        if info["emitted"]:
+            with prof.span("emit"):
+                for rid, tok in info["emitted"]:
+                    cb = s.requests[rid].on_token
+                    if cb is not None:
+                        cb(rid, tok)
+        for swap in info["preempted"]:
+            req = s.requests[swap.rid]
+            s.swapped[swap.rid] = swap
+            s.preempt_total += 1
             if s.tracer is not None:
-                tr = s.tracer
-                # chunks ran back-to-back from the tick start; the decode
-                # step follows them — lay the spans out on that timeline
-                cursor = s.rel(t_tick)
-                for rid, dtc in info["prefill_rids"]:
-                    tr.span("prefill_chunk", cursor, cursor + dtc, rid=rid)
-                    cursor += dtc
-                # one span per LANDED model step (a fused dispatch lands k
-                # steps at once): ``decode_tick`` span count stays equal to
-                # the session's ``decode_steps`` counter
-                if info["decode_steps"]:
-                    dt_step = info["decode_s"] / info["decode_steps"]
-                    for _ in range(info["decode_steps"]):
-                        tr.span("decode_tick", cursor, cursor + dt_step,
-                                rids=info["decode_rids"], n=info["occupied"])
-                        cursor += dt_step
-                # memory/power pressure counter tracks on the engine track
-                # (Perfetto renders them alongside the request spans): the
-                # arena/slot occupancy plus this tick's instantaneous power
-                # draw under the same model that charges the energy
-                if info["decode_steps"]:
-                    p_w = PM.instance_power_w(
-                        inst.chips, info["occupied"] / inst.capacity)
-                elif info["prefill_s"] > 0:
-                    p_w = inst.chips * PM.P_BUSY_W
-                else:
-                    p_w = inst.chips * PM.P_IDLE_W
-                tr.counter("blocks_in_use", cursor, info["blocks_in_use"])
-                tr.counter("occupied_rows", cursor, info["occupied"])
-                tr.counter("power_w", cursor, p_w)
-            for rid, tok in info["emitted"]:
-                cb = s.requests[rid].on_token
-                if cb is not None:
-                    cb(rid, tok)
-            for swap in info["preempted"]:
-                req = s.requests[swap.rid]
-                s.swapped[swap.rid] = swap
-                s.preempt_total += 1
-                if s.tracer is not None:
-                    t_sw = s.rel(time.perf_counter())
-                    s.tracer.instant("swap_out", t_sw, rid=swap.rid,
-                                     pages=swap.n_blocks)
-                    s.preempt_sids[swap.rid] = s.tracer.open_span(
-                        "preempted", t_sw, rid=swap.rid)
-                s.core.requeue_front(swap.rid, swap.t_arrival,
-                                     priority=req.priority,
-                                     deadline_s=req.deadline_s,
-                                     slo=req.slo)
-            for state in done:
-                completed.append(self._finish(state, inst))
-        self._post_tick(completed)
-        return completed
+                t_sw = s.rel(time.perf_counter())
+                s.tracer.instant("swap_out", t_sw, rid=swap.rid,
+                                 pages=swap.n_blocks)
+                s.preempt_sids[swap.rid] = s.tracer.open_span(
+                    "preempted", t_sw, rid=swap.rid)
+            s.core.requeue_front(swap.rid, swap.t_arrival,
+                                 priority=req.priority,
+                                 deadline_s=req.deadline_s,
+                                 slo=req.slo)
+        if done:
+            with prof.span("finish"):
+                for state in done:
+                    completed.append(self._finish(state, inst))
 
     def drain(self) -> List[InferenceResponse]:
         """Run the session to completion; returns every response.  Closes
@@ -1953,17 +2004,26 @@ class RealEngine:
         s.core.complete(state.rid, state.t_arrival, t_fin,
                         inst.ev.variant.accuracy)
         self.last_outputs[state.rid] = np.asarray(state.tokens, np.int64)
-        ttft = (state.t_first - state.t_arrival
-                if state.t_first is not None else 0.0)
+        t_admit = s.admit_t[state.rid]
+        # TTFT runs to the first token's value on the host, in three parts:
+        # queue (arrival → admission), prefill (→ last chunk dispatched)
+        # and first lag (→ the token read back)
+        ttft = prefill_wait = first_lag = 0.0
         if state.t_first is not None:
+            ttft = state.t_first - state.t_arrival
+            prefill_wait = state.t_prefilled - t_admit
+            first_lag = state.t_first - state.t_prefilled
             s.ttfts.append(ttft)
+            s.prefill_waits.append(prefill_wait)
+            s.first_lags.append(first_lag)
         hold = self.policy.hold_info(state.rid)
         resp = InferenceResponse(
             rid=state.rid, tokens=np.asarray(state.tokens, np.int64),
             slo=req.slo, priority=req.priority, state=DONE,
             t_arrival=state.t_arrival - s.t0, t_finish=t_fin - s.t0,
-            queue_delay_s=s.admit_t[state.rid] - state.t_arrival,
-            ttft_s=ttft, latency_s=t_fin - state.t_arrival,
+            queue_delay_s=t_admit - state.t_arrival, ttft_s=ttft,
+            prefill_wait_s=prefill_wait, first_lag_s=first_lag,
+            latency_s=t_fin - state.t_arrival,
             energy_j=s.meters[state.rid], preemptions=state.preempts,
             accuracy=inst.ev.variant.accuracy,
             variant=inst.ev.variant.name, deadline_s=req.deadline_s,
@@ -2097,6 +2157,13 @@ class RealEngine:
             "queue_delay_p95_s":
                 reg.histogram("queue_delay_s").percentile(95.0),
             "ttft_p95_s": reg.histogram("ttft_s").percentile(95.0),
+            # the TTFT split past admission, over the requests that got a
+            # first token (None where none did): last chunk dispatched, and
+            # the first token read back after it
+            "prefill_wait_p90_s": (latency_percentile(s.prefill_waits, 90.0)
+                                   if s.prefill_waits else None),
+            "first_lag_p90_s": (latency_percentile(s.first_lags, 90.0)
+                                if s.first_lags else None),
             "blocks_peak": s.blocks_peak,
             "preemptions": s.preempt_total,
             "prefill_chunks": chunks,

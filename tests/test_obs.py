@@ -324,8 +324,13 @@ def test_engine_trace_conserves_through_preemption_and_swapin(family):
     assert len(tr.instants("swap_out")) == m["preemptions"]
     assert len(tr.instants("swap_in")) == m["preemptions"]
     assert len(tr.spans("prefill_chunk")) == m["prefill_chunks"]
-    assert len(tr.spans("decode_tick")) == m["decode_steps"]
-    occupants = [d["args"]["rids"] for d in tr.spans("decode_tick")]
+    # one decode span per landed dispatch, from launch to landing: the
+    # steps they carry sum to the session's decode_steps
+    decodes = tr.spans("decode")
+    assert len(decodes) == m["decode_dispatches"]
+    assert sum(d["args"]["k"] for d in decodes) == m["decode_steps"]
+    assert all(d["t1"] >= d["t0"] for d in decodes)
+    occupants = [d["args"]["rids"] for d in decodes]
     assert any(len(o) > 1 for o in occupants)   # batched ticks, one event
 
     reg = eng.last_registry

@@ -381,8 +381,57 @@ def test_phase_profiler_routes_and_detaches():
     assert set(series) == {"decode_dispatch", "swap_d2h"}
     assert series["decode_dispatch"].count == 1
     assert series["swap_d2h"].samples[0] >= 0.0
-    assert set(PHASES) == {"prefill_chunk", "decode_dispatch",
-                           "decode_land", "swap_d2h", "swap_h2d"}
+    assert set(PHASES) == {"step", "admit", "tick", "prefill_chunk",
+                           "upload", "decode_dispatch", "decode_land",
+                           "emit", "finish", "swap_d2h", "swap_h2d"}
     prof.registry = None                        # detach again: silent
     prof.observe("decode_land", 1.0)
     assert reg.names() == set(CATALOG)
+
+
+def test_phase_span_annotates_stamps_and_observes():
+    opened = []
+
+    class Ann:                                  # stands in for jax's
+        def __init__(self, name, **meta):       # TraceAnnotation
+            self.rec = {"name": name, "meta": meta, "open": False}
+            opened.append(self.rec)
+
+        def __enter__(self):
+            self.rec["open"] = True
+
+        def __exit__(self, *exc):
+            self.rec["open"] = False
+
+    prof = PhaseProfiler(annotate=Ann)          # annotates, observes nothing
+    with prof.span("decode_dispatch", k=8, rows=32) as sp:
+        assert opened[-1]["open"]
+    assert opened == [{"name": "engine.decode_dispatch",
+                       "meta": {"k": 8, "rows": 32}, "open": False}]
+    assert sp.t1 >= sp.t0 and sp.seconds == sp.t1 - sp.t0
+    reg = MetricsRegistry.standard("r")
+    prof.registry = reg
+    with pytest.raises(ValueError):
+        with prof.span("tick"):                 # closed and observed even
+            raise ValueError                    # when the block raises
+    assert not opened[-1]["open"]
+    series = {d["phase"]: m for _, d, m in
+              reg.labeled_series("phase_latency_s")}
+    assert set(series) == {"tick"} and series["tick"].count == 1
+    with pytest.raises(AssertionError):
+        prof.span("warmup")                     # not a canonical phase
+
+
+def test_obs_imports_without_jax():
+    import os
+    import subprocess
+    import sys
+    import repro
+    src = os.path.dirname(os.path.dirname(os.path.abspath(repro.__file__)))
+    code = ("import sys; sys.modules['jax'] = None; import repro.obs; "
+            "p = repro.obs.PhaseProfiler(); "
+            "s = p.span('step'); s.__enter__(); s.__exit__(None, None, None)")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env=dict(os.environ, PYTHONPATH=src))
+    assert p.returncode == 0, p.stderr[-2000:]
