@@ -283,36 +283,56 @@ def attention_decode_slots_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 
 def gather_paged_kv(arena_k: jnp.ndarray, arena_v: jnp.ndarray,
-                    tables: jnp.ndarray):
-    """Materialize per-row contiguous KV views from a paged arena.
+                    layer: jnp.ndarray, tables: jnp.ndarray, n_kv_heads: int):
+    """Materialize one layer's per-row contiguous KV views from a paged arena.
 
-    arenas: (n_blocks, block_size, K, dh); tables: (b, n_pages) i32 arena
-    block ids (0-padded — block 0 is the junk sink, masked by lengths at the
-    attention).  Returns (k, v) shaped (b, n_pages·block_size, K, dh).  On
-    TPU the Pallas ``kernels.paged_attention`` kernel performs this gather
-    inside the BlockSpec index map instead of materializing it."""
+    arenas: (L, n_blocks, block_size, K·dh) per-token rows; layer: scalar
+    i32; tables: (b, n_pages) i32 arena block ids (0-padded — block 0 is the
+    junk sink, masked by lengths at the attention).  Returns (k, v) shaped
+    (b, n_pages·block_size, K, dh).  The layer and the pages are indexed in
+    ONE gather: slicing ``arena[layer]`` first would materialise a whole
+    layer of the arena per step.  On TPU the Pallas
+    ``kernels.paged_attention`` kernel performs this gather inside the
+    BlockSpec index map instead of materializing it."""
     b, n_pages = tables.shape
-    _, bs, K, dh = arena_k.shape
-    k = arena_k[tables].reshape(b, n_pages * bs, K, dh)
-    v = arena_v[tables].reshape(b, n_pages * bs, K, dh)
-    return k, v
+    bs, row = arena_k.shape[2:]
+    shape = (b, n_pages * bs, n_kv_heads, row // n_kv_heads)
+    return (arena_k[layer, tables].reshape(shape),
+            arena_v[layer, tables].reshape(shape))
+
+
+def write_paged_kv(arena_k: jnp.ndarray, arena_v: jnp.ndarray,
+                   layer: jnp.ndarray, write_bid: jnp.ndarray,
+                   write_off: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray):
+    """Scatter n new tokens' K/V (n, K, dh) into rows
+    ``[layer, write_bid[i], write_off[i]]`` of the (L, n_blocks, bs, K·dh)
+    arena — only those rows are written, so a donated arena carried through
+    the layer scan is updated in place."""
+    n = k.shape[0]
+    arena_k = arena_k.at[layer, write_bid, write_off].set(
+        k.reshape(n, -1).astype(arena_k.dtype))
+    arena_v = arena_v.at[layer, write_bid, write_off].set(
+        v.reshape(n, -1).astype(arena_v.dtype))
+    return arena_k, arena_v
 
 
 def attention_decode_paged_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                                  cos, sin, arena_k: jnp.ndarray,
-                                 arena_v: jnp.ndarray, tables: jnp.ndarray,
-                                 lengths: jnp.ndarray, write_bid: jnp.ndarray,
+                                 arena_v: jnp.ndarray, layer: jnp.ndarray,
+                                 tables: jnp.ndarray, lengths: jnp.ndarray,
+                                 write_bid: jnp.ndarray,
                                  write_off: jnp.ndarray, *, window=None):
-    """One paged continuous-batching decode step: each row scatters its new
-    K/V into arena block ``write_bid[i]`` at offset ``write_off[i]`` (the
-    junk block 0 for inactive rows) and attends over its own block table's
-    valid prefix.  x: (b, 1, d); arenas (n_blocks, bs, K, dh); tables
-    (b, n_pages); lengths (b,) i32.  Returns (out, arena_k, arena_v)."""
+    """One paged continuous-batching decode step of layer ``layer``: each row
+    scatters its new K/V into arena block ``write_bid[i]`` at offset
+    ``write_off[i]`` (the junk block 0 for inactive rows) and attends over
+    its own block table's valid prefix.  x: (b, 1, d); arenas (L, n_blocks,
+    bs, K·dh); tables (b, n_pages); lengths (b,) i32.  Returns (out,
+    arena_k, arena_v)."""
     q, k, v = _project_qkv(p, x, cfg, cos, sin)
     b = x.shape[0]
-    arena_k = arena_k.at[write_bid, write_off].set(k[:, 0].astype(arena_k.dtype))
-    arena_v = arena_v.at[write_bid, write_off].set(v[:, 0].astype(arena_v.dtype))
-    kc, vc = gather_paged_kv(arena_k, arena_v, tables)
+    arena_k, arena_v = write_paged_kv(arena_k, arena_v, layer, write_bid,
+                                      write_off, k[:, 0], v[:, 0])
+    kc, vc = gather_paged_kv(arena_k, arena_v, layer, tables, cfg.n_kv_heads)
     o = decode_attention_ref(q, kc, vc, lengths + 1, window=window)
     out = dense_apply(p["wo"], o.reshape(b, 1, cfg.n_heads * cfg.d_head))
     return out, arena_k, arena_v
@@ -320,23 +340,24 @@ def attention_decode_paged_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 def attention_prefill_paged_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                                   cos, sin, arena_k: jnp.ndarray,
-                                  arena_v: jnp.ndarray, table: jnp.ndarray,
-                                  positions: jnp.ndarray,
+                                  arena_v: jnp.ndarray, layer: jnp.ndarray,
+                                  table: jnp.ndarray, positions: jnp.ndarray,
                                   write_bid: jnp.ndarray,
                                   write_off: jnp.ndarray, *, window=None):
-    """One chunk of chunked prefill for a single sequence against the paged
-    arena: the chunk's rotated K/V scatter into their arena slots (junk
-    block 0 for the padded tail), then the chunk's queries attend causally
-    over the whole gathered table — cached prefix blocks included, so a
-    prefix-cache hit never replays shared tokens.
+    """One chunk of chunked prefill of layer ``layer`` for a single sequence
+    against the paged arena: the chunk's rotated K/V scatter into their
+    arena rows (junk block 0 for the padded tail), then the chunk's queries
+    attend causally over the whole gathered table — cached prefix blocks
+    included, so a prefix-cache hit never replays shared tokens.
 
     x: (1, C, d); table: (n_pages,) i32; positions: (C,) absolute token
     positions of the chunk.  Returns (out (1, C, d), arena_k, arena_v)."""
     q, k, v = _project_qkv(p, x, cfg, cos, sin)
     C = x.shape[1]
-    arena_k = arena_k.at[write_bid, write_off].set(k[0].astype(arena_k.dtype))
-    arena_v = arena_v.at[write_bid, write_off].set(v[0].astype(arena_v.dtype))
-    kc, vc = gather_paged_kv(arena_k, arena_v, table[None])   # (1, S, K, dh)
+    arena_k, arena_v = write_paged_kv(arena_k, arena_v, layer, write_bid,
+                                      write_off, k[0], v[0])
+    kc, vc = gather_paged_kv(arena_k, arena_v, layer, table[None],
+                             cfg.n_kv_heads)                  # (1, S, K, dh)
     S, K = kc.shape[1], kc.shape[2]
     H, dh = cfg.n_heads, cfg.d_head
     g = H // K

@@ -508,13 +508,19 @@ def init_block_arena(cfg: ModelConfig, n_blocks: int, block_size: int,
     list, refcounts, block tables).  Block 0 is the junk sink for masked
     writes — it is never handed to a sequence.
 
+    Each of ``k``/``v`` is ``(L, n_blocks, block_size, K·dh)``: one row per
+    token per layer, its KV heads side by side.  The paged programs carry
+    the arena through the layer scan and scatter only each step's new rows
+    into it; with this row layout XLA keeps that scatter in place for every
+    head shape (split ``(K, dh)`` minor dims such as 2×64 make it relayout
+    and copy the whole arena instead).
+
     With ``mesh`` the arena comes back committed under the GSPMD rule
-    (KV heads over "model", block dims unsharded — ``sharding.rules.
-    arena_spec``) so the serving engine's donated prefill/decode jits
-    specialize to the sharded layout."""
+    (whole KV heads of each row over "model", block dims unsharded —
+    ``sharding.rules.arena_spec``) so the serving engine's donated
+    prefill/decode jits specialize to the sharded layout."""
     assert supports_slots(cfg), f"paged arena unsupported for {cfg.family}"
-    K, dh = cfg.n_kv_heads, cfg.d_head
-    shape = (cfg.n_layers, n_blocks, block_size, K, dh)
+    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads * cfg.d_head)
     if mesh is None:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     import jax
@@ -522,6 +528,22 @@ def init_block_arena(cfg: ModelConfig, n_blocks: int, block_size: int,
     sh = SR.arena_shardings(mesh, cfg)
     zeros = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sh)
     return {"k": zeros(), "v": zeros()}
+
+
+def _scan_paged_layers(body, x, params: Params, arena: Params, windows,
+                       is_global):
+    """Run ``body`` over the layers with the arena in the scan CARRY, never
+    among the scanned inputs or stacked outputs: a scanned arena is sliced
+    per layer and restacked into a fresh buffer, rewriting all of it every
+    step, while a carried one takes each layer's scatter in place.  The
+    layer index rides in ``xs`` for the helpers' ``arena[layer, ...]``.
+    Returns (x, arena_k, arena_v)."""
+    n_layers = arena["k"].shape[0]
+    (x, k2, v2), _ = jax.lax.scan(
+        body, (x, arena["k"], arena["v"]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32), windows,
+         is_global))
+    return x, k2, v2
 
 
 def prefill_paged_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
@@ -555,13 +577,14 @@ def prefill_paged_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     windows = jnp.asarray(windows_np)
     is_global = jnp.asarray(is_global_np)
 
-    def body(x, xs):
-        p, ak, av, win, isg = xs
+    def body(carry, xs):
+        x, ak, av = carry
+        p, layer, win, isg = xs
         cos = jnp.where(isg, cos_g, cos_l)
         sin = jnp.where(isg, sin_g, sin_l)
         h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
-        a, ak2, av2 = L.attention_prefill_paged_apply(
-            p["attn"], h, cfg, cos, sin, ak, av, table, positions,
+        a, ak, av = L.attention_prefill_paged_apply(
+            p["attn"], h, cfg, cos, sin, ak, av, layer, table, positions,
             write_bid, write_off, window=win if has_win else None)
         x = x + a
         h = L.rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
@@ -569,10 +592,9 @@ def prefill_paged_lm(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             y, _ = M.moe_apply(p["moe"], h, cfg)
         else:
             y = L.mlp_apply(p["mlp"], h, cfg)
-        return x + y, (ak2, av2)
+        return (x + y, ak, av), None
 
-    x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], arena["k"],
-                                         arena["v"], windows, is_global))
+    x, k2, v2 = _scan_paged_layers(body, x, params, arena, windows, is_global)
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = lm_logits(params, x, cfg)
     return logits, {"k": k2, "v": v2}
@@ -606,13 +628,14 @@ def decode_paged_lm(params: Params, arena: Params, tokens: jnp.ndarray,
     windows = jnp.asarray(windows_np)
     is_global = jnp.asarray(is_global_np)
 
-    def body(x, xs):
-        p, ak, av, win, isg = xs
+    def body(carry, xs):
+        x, ak, av = carry
+        p, layer, win, isg = xs
         cos = jnp.where(isg, cos_g, cos_l)
         sin = jnp.where(isg, sin_g, sin_l)
         h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
-        a, ak2, av2 = L.attention_decode_paged_apply(
-            p["attn"], h, cfg, cos, sin, ak, av, tables, lengths,
+        a, ak, av = L.attention_decode_paged_apply(
+            p["attn"], h, cfg, cos, sin, ak, av, layer, tables, lengths,
             write_bid, write_off, window=win if has_win else None)
         x = x + a
         h = L.rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
@@ -620,10 +643,9 @@ def decode_paged_lm(params: Params, arena: Params, tokens: jnp.ndarray,
             y, _ = M.moe_apply(p["moe"], h, cfg)
         else:
             y = L.mlp_apply(p["mlp"], h, cfg)
-        return x + y, (ak2, av2)
+        return (x + y, ak, av), None
 
-    x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], arena["k"],
-                                         arena["v"], windows, is_global))
+    x, k2, v2 = _scan_paged_layers(body, x, params, arena, windows, is_global)
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = lm_logits(params, x, cfg)
     return logits[:, 0, :], {"k": k2, "v": v2}

@@ -157,11 +157,12 @@ def _variant_fns(ev: EngineVariant) -> dict:
 
 def _paged_fns(ev: EngineVariant) -> dict:
     """Jitted chunked-prefill / paged-decode entry points (same per-variant
-    sharing discipline as ``_variant_fns``).  The arena is DONATED: every
-    call scatters a handful of K/V rows into a buffer that is megabytes —
-    without donation XLA copies the whole arena per step, and the copy
-    dominates the decode tick on large pools.  Callers must treat the
-    passed-in arena as consumed (the instance reassigns from the result).
+    sharing discipline as ``_variant_fns``).  The arena, ``{"k","v"}`` each
+    ``(L, n_blocks, bs, K·dh)``, is DONATED: every call scatters a handful
+    of K/V rows into a buffer of gigabytes — without donation XLA copies
+    the whole arena per step, and the copy dominates the decode tick on
+    large pools.  Callers must treat the passed-in arena as consumed (the
+    instance reassigns from the result).
 
     ``decode_multi`` is the device-resident hot path: ``k`` fused greedy
     steps (static — one compile per (bucket, k)) with on-device argmax
@@ -169,12 +170,13 @@ def _paged_fns(ev: EngineVariant) -> dict:
     steady-state decode never uploads host state.  ``next`` and ``lengths``
     are donated alongside the arena (updated in place); ``tables`` and the
     active mask are reused read-only across ticks.  ``restore_paged`` /
-    ``gather_pages`` are the swap staging pair: a donated in-place page
-    scatter for swap-in (the un-jitted ``.at[].set`` copied the whole
-    arena) and a page gather whose result is copied device→host
-    asynchronously at swap-out.  ``first_token`` (a final chunk's
-    last-position argmax) and ``set_next`` (its scatter into an uploaded
-    ``next`` buffer) are the small steps between them.
+    ``gather_pages`` are the swap staging pair over the block dim (images
+    ``(L, n_pages, bs, K·dh)``): a donated in-place page scatter for
+    swap-in (the un-jitted ``.at[].set`` copied the whole arena) and a page
+    gather whose result is copied device→host asynchronously at swap-out.
+    ``first_token`` (a final chunk's last-position argmax) and ``set_next``
+    (its scatter into an uploaded ``next`` buffer) are the small steps
+    between them.
 
     Every entry is a named function, so each XLA module carries its name
     (``jit_prefill_paged``, ``jit_decode_multi``, ...) into the device
@@ -329,7 +331,7 @@ class _SwapState:
     t_prefilled: Optional[float]
     cached_tokens: int
     preempts: int
-    img_k: object                  # (L, >=n_blocks, bs, K, dh) device or np
+    img_k: object                  # (L, >=n_blocks, bs, K·dh) device or np
     img_v: object
     nb: int                        # pages actually used (img may be padded)
     tree_blocks: int = 0           # leading pages tree-backed at swap-out
@@ -342,7 +344,7 @@ class _SwapState:
 
     @property
     def host_k(self) -> np.ndarray:
-        """(L, n_blocks, bs, K, dh) host image — materialises (and caches)
+        """(L, n_blocks, bs, K·dh) host image — materialises (and caches)
         the staged device copy on first read."""
         if not isinstance(self.img_k, np.ndarray):
             self.img_k = np.asarray(self.img_k)[:, :self.nb]

@@ -1,6 +1,6 @@
 """Host-side block allocator for the paged KV arena.
 
-The device arena is one preallocated ``(L, n_blocks, block_size, K, dh)``
+The device arena is one preallocated ``(L, n_blocks, block_size, K·dh)``
 tensor pair per instance (``models.registry.make_block_arena``); this module
 owns the *map* of it: which blocks are free, who references each block, and
 which physical blocks make up each sequence's logical token range (the block

@@ -243,10 +243,12 @@ def cache_specs(cache_shape_tree, mesh, cfg: ModelConfig, *,
 
 
 def arena_spec(mesh, cfg: ModelConfig) -> P:
-    """PartitionSpec for the paged KV arena ``(L, n_blocks, bs, K, dh)``.
+    """PartitionSpec for the paged KV arena ``(L, n_blocks, bs, K·dh)``.
 
-    KV heads shard over ``model``; the block map dims (n_blocks, bs) stay
-    unsharded because block tables live host-side and index whole pages.
+    Each token row's KV heads shard over ``model`` — the row dim splits
+    only when ``n_kv_heads`` divides the axis, so every shard holds whole
+    heads; the block map dims (n_blocks, bs) stay unsharded because block
+    tables live host-side and index whole pages.
 
     Unlike :func:`cache_specs`, non-divisible head counts are an explicit
     ERROR here rather than a silent fallback: a paged arena has no
@@ -258,9 +260,9 @@ def arena_spec(mesh, cfg: ModelConfig) -> P:
     """
     n_model = mesh.shape[MODEL_AXIS]
     if n_model == 1:
-        return P(None, None, None, None, None)
+        return P(None, None, None, None)
     if cfg.n_kv_heads % n_model == 0:
-        return P(None, None, None, MODEL_AXIS, None)
+        return P(None, None, None, MODEL_AXIS)
     raise ValueError(
         f"paged KV arena cannot shard on the head dim: n_kv_heads="
         f"{cfg.n_kv_heads} is not divisible by the mesh's model axis "

@@ -126,6 +126,22 @@ def test_pipelined_parity_preemption_and_partial_swapin(family):
     (staged async swap-out, partial swap-in through the radix tree) while
     in-flight pipelined work must be landed before every victim snapshot.
     Greedy outputs must equal the synchronous reference's exactly."""
+    _check_preemption_parity(family)
+
+
+@pytest.mark.parametrize("heads", [dict(n_heads=4, n_kv_heads=2, d_head=64),
+                                   dict(n_heads=16, n_kv_heads=8,
+                                        d_head=128)],
+                         ids=["kv2x64", "kv8x128"])
+def test_preemption_parity_at_served_head_shapes(heads):
+    """The same preemption + partial swap-in parity at the head shapes the
+    benchmark serves, whose arena rows are K·dh = 128 and 1024 wide: the
+    staged page images and restores move whole rows of that width."""
+    _check_preemption_parity(
+        ENG.build_engine_family(CFG.with_(**heads), fracs=(1.0,)))
+
+
+def _check_preemption_parity(family):
     prompts = _prompts((6, 6, 6, 6), seed=5, shared=16)
     pipe, sync = _pair(family, n_blocks=14, preemption=True)
     m_pipe = pipe._serve_prompts(prompts, n_new=16)

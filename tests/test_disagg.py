@@ -190,12 +190,13 @@ def test_arena_spec_explicit_error_on_non_divisible_heads():
     from jax.sharding import PartitionSpec as P
     glm4 = get_smoke_config("glm4-9b")          # n_kv_heads=2, GQA
     assert glm4.n_kv_heads == 2
-    # divisible: KV heads shard over model, block-map dims stay host-side
+    # divisible: each token row's whole KV heads shard over model,
+    # block-map dims stay host-side
     assert SR.arena_spec(_FakeMesh(data=4, model=2), glm4) == \
-        P(None, None, None, "model", None)
+        P(None, None, None, "model")
     # model axis 1: fully replicated (the single-device serving path)
     assert SR.arena_spec(_FakeMesh(data=8, model=1), glm4) == \
-        P(None, None, None, None, None)
+        P(None, None, None, None)
     # non-divisible: an explicit error, not silent GSPMD padding
     with pytest.raises(ValueError, match="n_kv_heads"):
         SR.arena_spec(_FakeMesh(data=2, model=4), glm4)

@@ -348,6 +348,73 @@ def test_paged_decode_matches_slotted_decode(params):
         nxt_p[1, 0] = tp
 
 
+# the two head shapes the benchmark serves (qwen2-0.5b, qwen3-1.7b), at
+# smoke width: the arena's per-token rows are K·dh = 128 and 1024 wide
+HEAD_SHAPES = {"kv2x64": dict(n_heads=4, n_kv_heads=2, d_head=64),
+               "kv8x128": dict(n_heads=16, n_kv_heads=8, d_head=128)}
+
+
+@pytest.mark.parametrize("heads", sorted(HEAD_SHAPES))
+def test_carried_arena_prefill_and_fused_decode_match_contiguous(heads):
+    """Chunked prefill, then fused decode, then one more step, all through
+    the arena carried across the layer scan, against the contiguous slotted
+    cache: same greedy tokens, same logits.  The sequence's blocks are out
+    of order and two inactive rows scatter into the junk block alongside."""
+    cfg = CFG.with_(**HEAD_SHAPES[heads])
+    params = R.init_params(jax.random.fold_in(KEY, 7), cfg)
+    toks = jax.random.randint(jax.random.fold_in(KEY, 8), (1, 13), 0,
+                              cfg.vocab_size)
+    bs, P, C, k = 4, 8, 8, 4
+    table = np.array([6, 2, 9, 4, 11, 0, 0, 0], np.int32)  # 13 + k + 1 toks
+    arena = R.make_block_arena(cfg, 12, bs, dtype=jnp.float32)
+    assert arena["k"].shape == (cfg.n_layers, 12, bs,
+                                cfg.n_kv_heads * cfg.d_head)
+    n_past = 0
+    while n_past < 13:
+        true_c = min(C, 13 - n_past)
+        chunk = np.zeros((1, C), np.int32)
+        chunk[0, :true_c] = np.asarray(toks)[0, n_past:n_past + true_c]
+        lg, arena = R.prefill_paged(params, {"tokens": jnp.asarray(chunk)},
+                                    cfg, arena, jnp.asarray(table), n_past,
+                                    true_c)
+        n_past += true_c
+    cache = R.make_slot_cache(cfg, 1, 32, dtype=jnp.float32)
+    lgs, k_all, v_all = R.prefill_kv(params, {"tokens": toks}, cfg)
+    cache["k"] = cache["k"].at[:, 0, :13].set(k_all[:, 0])
+    cache["v"] = cache["v"].at[:, 0, :13].set(v_all[:, 0])
+    cache["lengths"] = jnp.array([13], jnp.int32)
+    np.testing.assert_allclose(np.asarray(lg[0, true_c - 1]),
+                               np.asarray(lgs[0, 12]), rtol=2e-4, atol=2e-4)
+    first = int(jnp.argmax(lgs[0, 12]))
+    assert int(jnp.argmax(lg[0, true_c - 1])) == first
+
+    tables = np.zeros((3, P), np.int32)
+    tables[1] = table
+    nxt = np.zeros((3, 1), np.int32)
+    nxt[1, 0] = first
+    active = jnp.asarray(np.array([False, True, False]))
+    toks_p, arena, nxt_p, ln_p = R.decode_paged_multi(
+        params, arena, {"tokens": jnp.asarray(nxt)}, cfg,
+        jnp.asarray(tables), jnp.asarray(np.array([0, 13, 0], np.int32)),
+        active, k)
+    nxt_s = jnp.array([[first]], jnp.int32)
+    for step in range(k):
+        lg_s, cache = R.decode_slots(params, cache, {"tokens": nxt_s}, cfg,
+                                     jnp.array([True]))
+        tok = int(jnp.argmax(lg_s[0]))
+        assert int(toks_p[step, 1]) == tok, f"step {step}"
+        nxt_s = jnp.array([[tok]], jnp.int32)
+    assert int(ln_p[1]) == 13 + k and int(nxt_p[1, 0]) == tok
+    # one more step reads every row the fused loop wrote
+    lg_p, _ = R.decode_paged(params, arena, {"tokens": nxt_p}, cfg,
+                             jnp.asarray(tables), ln_p, active)
+    lg_s, _ = R.decode_slots(params, cache, {"tokens": nxt_s}, cfg,
+                             jnp.array([True]))
+    assert int(jnp.argmax(lg_p[1])) == int(jnp.argmax(lg_s[0]))
+    np.testing.assert_allclose(np.asarray(lg_p[1]), np.asarray(lg_s[0]),
+                               rtol=2e-4, atol=2e-4)
+
+
 # =============================================================================
 # engine level: paged vs slotted parity, reclamation, open loop
 # =============================================================================

@@ -3,12 +3,17 @@ widths, with no chip attached: the engine's step programs (chunked prefill,
 fused paged decode at k=1 and k=8, the slotted prefill and decode) must fit
 one chip's 16 GiB, the paged programs must also compile sharded over the
 four chips of a v5e:2x2, and the Pallas kernels must lower to Mosaic
-(``tpu_custom_call``).
+(``tpu_custom_call``).  At the benchmark's engine shapes
+(``bench/configs``) the paged programs of both head shapes must write the
+KV arena in place: no arena-sized temporaries, and no instruction shaped
+like the arena or one layer of it except the arena's own scatters.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the tests of this file run in the
 process that does.  Shapes are those ``chip_smoke.py`` runs."""
+import json
 import os
+import re
 import sys
 
 import jax
@@ -18,8 +23,8 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
-sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                                "..")))
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, ROOT)
 
 import chip_smoke as CS  # noqa: E402
 from repro.configs import get_config  # noqa: E402
@@ -133,6 +138,87 @@ def test_prefill_paged_compiles_and_fits(params, arena, one_chip):
 @pytest.mark.parametrize("k", [1, 8])
 def test_decode_paged_multi_compiles_and_fits(params, arena, one_chip, k):
     _fits_one_chip(_decode_paged_multi(params, arena, one_chip, k))
+
+
+def _bench_engine(name):
+    with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
+        bc = json.load(f)
+    return get_config(bc["program_model"]), bc["engine"]
+
+
+def _compile_bench_step(name, step, sharding):
+    """The engine's paged program ``step`` ("prefill" or "decode_k<k>") at
+    the configuration's engine shapes; returns (compiled, arena shape)."""
+    cfg, e = _bench_engine(name)
+    bs, B = e["block_size"], e["max_seqs"]
+    n_pages = -(-e["max_len"] // bs)
+    params = _shapes(jax.eval_shape(
+        lambda: R.init_params(jax.random.PRNGKey(0), cfg)), sharding)
+    arena = _shapes(jax.eval_shape(lambda: R.make_block_arena(
+        cfg, e["n_blocks"], bs, dtype=jnp.float32)), sharding)
+    if step == "prefill":
+        fn = jax.jit(lambda p, t, ar, tb, n0, tc: R.prefill_paged(
+            p, {"tokens": t}, cfg, ar, tb, n0, tc), donate_argnums=(2,))
+        compiled = fn.lower(
+            params, _sds((1, e["chunk_blocks"] * bs), jnp.int32, sharding),
+            arena, _sds((n_pages,), jnp.int32, sharding),
+            _sds((), jnp.int32, sharding), _sds((), jnp.int32, sharding)
+        ).compile()
+    else:
+        k = int(step.removeprefix("decode_k"))
+        fn = jax.jit(lambda p, ar, t, tb, ln, act: R.decode_paged_multi(
+            p, ar, {"tokens": t}, cfg, tb, ln, act, k),
+            donate_argnums=(1, 2, 4))
+        compiled = fn.lower(
+            params, arena, _sds((B, 1), jnp.int32, sharding),
+            _sds((B, n_pages), jnp.int32, sharding),
+            _sds((B,), jnp.int32, sharding),
+            _sds((B,), jnp.bool_, sharding)).compile()
+    return compiled, arena["k"].shape
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                    re.M)
+_COMPUTATION = re.compile(r"^%(\S+) \(.*\{$", re.M)
+
+
+def _arena_shaped_ops(hlo: str, arena_shape) -> list:
+    """Every instruction of the optimized module, fused computations
+    included, whose result is the whole arena or one layer of it, as
+    (opcode, fused root opcode or None).  A fusion is named by the opcode
+    of its computation's ROOT, so a scatter fused with its index math
+    reads as the scatter it is."""
+    full = ",".join(map(str, arena_shape))
+    layer = ",".join(map(str, arena_shape[1:]))
+    roots = {}
+    for m in _COMPUTATION.finditer(hlo):
+        body = hlo[m.end():hlo.find("\n}", m.end())]
+        root = re.search(r"^\s*ROOT %\S+ = \S+ ([\w-]+)\(", body, re.M)
+        roots[m.group(1)] = root.group(1) if root else None
+    ops = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m is None or m.group(1) not in (full, layer, "1," + layer):
+            continue
+        called = re.search(r"calls=%([\w.-]+)", line)
+        ops.append((m.group(2), roots.get(called.group(1)) if called
+                    else None))
+    return ops
+
+
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "qwen2-0.5b"])
+@pytest.mark.parametrize("step", ["decode_k1", "decode_k8", "prefill"])
+def test_paged_step_writes_the_arena_in_place(one_chip, name, step):
+    compiled, arena_shape = _compile_bench_step(name, step, one_chip)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2**30, f"{name} {step}: {temp / 2**30:.2f} GiB temporaries"
+    ops = _arena_shaped_ops(compiled.as_text(), arena_shape)
+    plumbing = {"parameter", "get-tuple-element"}
+    stray = [op for op in ops if op[0] not in plumbing
+             and "scatter" not in (op[0], op[1])]
+    assert not stray, f"{name} {step}: arena-shaped {stray}"
+    # K and V: one scatter each, no other arena write
+    assert sum(op[0] == "scatter" for op in ops) == 2, ops
 
 
 def test_slotted_prefill_compiles_and_fits(params, one_chip):
