@@ -1,5 +1,6 @@
-"""Model FLOPs of the window's work over what the chip's bf16 peak gives in
-the device's busy time, in percent.
+"""Model FLOPs of the window's work over what the chips' bf16 peak gives in
+the devices' busy time, in percent: the busy time is the average over the
+chips used, so the peak is one chip's times their number.
 
 The work is what reached the client inside the traced window: every decode
 token after a request's first, at its own context, and the prefill of every
@@ -24,4 +25,5 @@ def read(rec):
         for j, t in enumerate(ts[1:], start=1):
             if t < T:
                 work += flops.decode_token(m, p + j)
-    return 100.0 * work / (tr["busy_s"] * rec["peaks"]["bf16_flops_per_s"])
+    peak = rec["chips"] * rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * work / (tr["busy_s"] * peak)

@@ -1,22 +1,31 @@
-"""Dense decoder LMs of the Qwen2/Qwen3 kind: the benchmark's own weights and
-its plain float32 reference.
+"""Dense decoder LMs of the Qwen2/Qwen3/GLM-4 kind: the benchmark's own
+weights and its plain float32 reference.
 
-The layer equations (HF ``Qwen2ForCausalLM`` / ``Qwen3ForCausalLM``):
+The layer equations (HF ``Qwen2ForCausalLM`` / ``Qwen3ForCausalLM`` /
+``GlmForCausalLM``):
 
     h   = x + o_proj(attn(rope(qn(q_proj(rms1(x)))), rope(kn(k_proj(.))), v_proj(.)))
     out = h + down(silu(gate(rms2(h))) * up(rms2(h)))
 
 with RMSNorm ``x * rsqrt(mean(x^2) + eps) * scale``, rotary embeddings on
-the two halves of each head (``rotate_half``), grouped-query attention
+the first ``partial_rotary_factor`` of each head's dims (all of them for
+Qwen, half for GLM-4; the rest pass through), grouped-query attention
 (query head ``i`` reads key/value head ``i // (H / K)``), causal softmax at
 scale ``d_head^-0.5``, per-head RMSNorm of q and k before the rotation
 (``qk_norm``, Qwen3) or biases on the q/k/v projections (``qkv_bias``,
-Qwen2), a final RMSNorm, and logits against the tied embedding table.
+Qwen2, GLM-4), a final RMSNorm, and logits against the tied embedding table
+or an untied ``lm_head`` (GLM-4).  Qwen rotates the two halves of the
+rotated dims (``rotate_half``); GLM-4 rotates interleaved pairs
+``(x[2i], x[2i+1])`` (``rope_interleaved``).
 
 Nothing here imports the program.  The weights are drawn from the seed by
-one jitted call, in bf16, and stacked over layers; ``to_program`` only
-re-keys these same arrays into the program's parameter tree (no copy), and
-the reference reads them as they are.
+one jitted call, in bf16, and stacked over layers; ``to_program`` re-keys
+these same arrays into the program's parameter tree (no copy), and the
+reference reads them as they are.  One layout change is not a re-key: the
+program rotates halves, so for an interleaved model ``to_program`` reorders
+each head's rotated columns of ``wq``, ``wk`` and their biases (pair ``i``
+to columns ``i`` and ``i + r/2``).  Queries and keys are reordered alike,
+so every attention score, and so every logit, is the same.
 """
 from __future__ import annotations
 
@@ -39,6 +48,8 @@ def dims(cfg: Dict) -> Dict:
                 V=V, Vpad=-(-V // 512) * 512 if V >= 4096 else -(-V // 16) * 16,
                 eps=c["rms_norm_eps"], theta=float(c["rope_theta"]),
                 qk_norm=bool(a["qk_norm"]), qkv_bias=bool(a["qkv_bias"]),
+                partial=float(c.get("partial_rotary_factor", 1.0)),
+                interleaved=bool(a.get("rope_interleaved", False)),
                 tied=bool(c["tie_word_embeddings"]),
                 init_std=float(c["initializer_range"]),
                 dtype=jnp.dtype(c["torch_dtype"]))
@@ -46,11 +57,13 @@ def dims(cfg: Dict) -> Dict:
 
 def check_program(pcfg, m: Dict) -> None:
     """Refuse a program model (``repro.configs``, handed in) that is not the
-    configuration file's: every width, the norms and biases, the dtype."""
+    configuration file's: every width, the norms and biases, the rotated
+    share of each head, the dtype."""
     want = {"n_layers": m["L"], "d_model": m["d"], "n_heads": m["H"],
             "n_kv_heads": m["K"], "d_head": m["dh"], "d_ff": m["ff"],
             "vocab_size": m["V"], "qk_norm": m["qk_norm"],
             "qkv_bias": m["qkv_bias"], "tie_embeddings": m["tied"],
+            "partial_rotary": m["partial"],
             "rope_theta": m["theta"], "norm_eps": m["eps"],
             "padded_vocab": m["Vpad"], "family": "dense", "act": "silu"}
     bad = {k: (getattr(pcfg, k), v) for k, v in want.items()
@@ -105,15 +118,64 @@ def _draw(key, m: Dict) -> Dict:
     return w
 
 
-def make_weights(cfg: Dict, seed: int) -> Dict:
-    """All weights from the seed, on the device, in one jitted call."""
+def make_weights(cfg: Dict, seed: int, shardings=None) -> Dict:
+    """All weights from the seed, on the device, in one jitted call.
+    ``shardings``: the program's parameter shardings (a tree shaped as
+    ``to_program``'s); each weight is then drawn laid out as the program's
+    parameter that holds it, so no device holds more than its share."""
     m = dims(cfg)
-    return jax.jit(lambda k: _draw(k, m))(key_of(seed))
+    if shardings is None:
+        return jax.jit(lambda k: _draw(k, m))(key_of(seed))
+    return jax.jit(lambda k: _draw(k, m),
+                   out_shardings=_on_reference(shardings, m))(key_of(seed))
+
+
+def _on_reference(shardings, m: Dict):
+    """The program's shardings laid on the reference's tree: each weight
+    takes the sharding of the parameter that ``_rekey`` puts it in."""
+    shape = jax.eval_shape(lambda: _draw(jax.random.PRNGKey(0), m))
+    names = jax.tree_util.tree_map_with_path(
+        lambda path, _: jax.tree_util.keystr(path), shape)
+    keyed = _rekey(names, m)
+    if jax.tree.structure(keyed) != jax.tree.structure(shardings):
+        raise RuntimeError("shardings do not fit the program's parameters")
+    placed = dict(zip(jax.tree.leaves(keyed), jax.tree.leaves(shardings)))
+    return jax.tree.map(placed.get, names)
+
+
+def _rotary_order(m: Dict) -> np.ndarray:
+    """Program column ``j`` of a head takes reference column ``order[j]``:
+    the interleaved pairs' first members, then their second, then the dims
+    that do not rotate."""
+    dh, r = m["dh"], int(m["dh"] * m["partial"])
+    return np.concatenate([np.arange(0, r, 2), np.arange(1, r, 2),
+                           np.arange(r, dh)])
+
+
+@jax.jit
+def _halves(arrs: Dict, order) -> Dict:
+    """Each head's columns of every array in ``arrs`` taken in ``order``."""
+    dh = order.shape[0]
+    return {k: jnp.take(a.reshape(*a.shape[:-1], -1, dh), order,
+                        axis=-1).reshape(a.shape) for k, a in arrs.items()}
 
 
 def to_program(w: Dict, cfg: Dict) -> Dict:
-    """The same arrays under the program's parameter names."""
+    """The same arrays under the program's parameter names, with an
+    interleaved model's rotated columns in the program's order."""
     m = dims(cfg)
+    p = _rekey(w, m)
+    if m["interleaved"]:
+        attn = p["layers"]["attn"]
+        arrs = {(k, part): attn[k][part] for k in ("wq", "wk")
+                for part in attn[k]}
+        for (k, part), a in _halves(arrs, _rotary_order(m)).items():
+            attn[k] = dict(attn[k], **{part: a})
+    return p
+
+
+def _rekey(w: Dict, m: Dict) -> Dict:
+    """``w``'s leaves, whatever they are, under the program's names."""
     lw = w["layers"]
 
     def dense(name, bias):
@@ -144,14 +206,25 @@ def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
-    """x (S, heads, dh); rotate_half convention."""
-    half = x.shape[-1] // 2
+def _rope(x, pos, theta, partial=1.0, interleaved=False):
+    """x (S, heads, dh).  The first ``partial`` of each head's dims rotate,
+    in pairs ``(x[i], x[i + r/2])`` (``rotate_half``) or, ``interleaved``,
+    ``(x[2i], x[2i+1])``; pair ``i`` turns at ``theta^(-2i/r)``.  The other
+    dims pass through."""
+    r = int(x.shape[-1] * partial)
+    half = r // 2
     freq = 1.0 / theta ** (jnp.arange(half, dtype=F32) / half)
     ang = pos.astype(F32)[:, None] * freq
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    xr = x[..., :r]
+    if interleaved:
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        -1).reshape(xr.shape)
+    else:
+        x1, x2 = xr[..., :half], xr[..., half:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out if r == x.shape[-1] else jnp.concatenate([out, x[..., r:]], -1)
 
 
 def _identity(x):
@@ -181,7 +254,9 @@ def hidden(w: Dict, tokens, m: Dict, q=_identity):
         if m["qk_norm"]:
             qh = _rms(qh, lw["q_norm"], m["eps"])
             kh = _rms(kh, lw["k_norm"], m["eps"])
-        qh, kh = _rope(qh, pos, m["theta"]), _rope(kh, pos, m["theta"])
+        rot = dict(partial=m["partial"], interleaved=m["interleaved"])
+        qh = _rope(qh, pos, m["theta"], **rot)
+        kh = _rope(kh, pos, m["theta"], **rot)
         kh = jnp.repeat(kh, H // K, axis=1)
         vh = jnp.repeat(vh, H // K, axis=1)
         s = jnp.einsum("qhd,khd->hqk", q(qh), q(kh)) * dh ** -0.5

@@ -4,6 +4,7 @@ A run record is a dict:
 
 * ``cfg``, ``m``: the configuration file and its model dimensions
   (``models/<family>.dims``);
+* ``chips``: the chips the engine spans;
 * ``seconds``: the measured window;
 * ``requests``: one dict per request sent in the window: ``t_s`` (scheduled
   arrival), ``prompt_len``, ``n_out``, ``times`` (seconds after the window

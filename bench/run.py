@@ -14,9 +14,11 @@ checkout; draw the weights from the seed, configure the paged engine, which
 compiles or loads every bucket it can dispatch, and generate the traffic),
 then ``--seconds`` of serving, then a drain of the requests still open, then
 the correctness check against the plain float32 reference
-(``bench/models/<family>.py``).  With ``--trace 1`` the profiler records the
-window and the run reports the per-layer metrics; with ``--trace 0`` the
-end-to-end ones.
+(``bench/models/<family>.py``).  A configuration whose engine names a
+``mesh`` (``{"data": 2, "model": 2}``) is served by one engine sharded over
+that many chips, as many as its cell asks for.  With ``--trace 1`` the
+profiler records the window and the run reports the per-layer metrics; with
+``--trace 0`` the end-to-end ones.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` a
@@ -78,15 +80,46 @@ def cell_of(bench: Dict, workload: str) -> Dict:
         raise SystemExit(f"unknown workload {workload!r}; have {sorted(cells)}")
     cell = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    cfg = load_json(os.path.join(ROOT, conf["file"]))
+    if chips_of(cfg) != cell["chips"]:
+        raise SystemExit(f"{workload} asks for {cell['chips']} chip(s); its "
+                         f"engine spans {chips_of(cfg)}")
 
     def applies(m):
         return workload in m.get("workloads", [workload])
     return {"cell": cell,
-            "cfg": load_json(os.path.join(ROOT, conf["file"])),
+            "cfg": cfg,
             "traffic": load_json(os.path.join(BENCH, "traffic",
                                               cell["traffic"] + ".json")),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
             "per_layer": [m for m in bench["per_layer"] if applies(m)]}
+
+
+def chips_of(cfg: Dict) -> int:
+    """The chips a configuration's engine spans: its mesh's, else one."""
+    mesh = cfg["engine"].get("mesh")
+    return int(np.prod(list(mesh.values()))) if mesh else 1
+
+
+# The program's model options that a configuration may set where the
+# program's registry departs from the published value.  Only constants of the
+# arithmetic: a width, a norm, a bias or the rotary layout stays the
+# registry's, so that ``check_program`` refuses any departure there.
+PROGRAM_OPTIONS = ("norm_eps",)
+
+
+def program_model(cfg: Dict, pcfg=None):
+    """The program's model for a configuration (``pcfg`` in its place, in
+    tests), with the ``PROGRAM_OPTIONS`` the file sets in
+    ``program_options``; any other key is refused."""
+    from repro.configs import get_config
+    opts = cfg.get("program_options", {})
+    other = sorted(set(opts) - set(PROGRAM_OPTIONS))
+    if other:
+        raise RuntimeError(f"program_options may set only {PROGRAM_OPTIONS}; "
+                           f"{cfg['name']} sets {other}")
+    pcfg = pcfg or get_config(cfg["program_model"])
+    return pcfg.with_(**opts)
 
 
 def family(cfg: Dict):
@@ -119,17 +152,23 @@ class CompileCounter:
 
 
 class Harness:
-    """One configuration's engine on one chip, served window by window."""
+    """One configuration's engine, on one chip or sharded over the mesh its
+    engine names, served window by window."""
 
     def __init__(self, cfg: Dict, pcfg=None):
         import jax
-        from repro.configs import get_config
         self.jax = jax
         self.cfg = cfg
         self.fam = family(cfg)
         self.m = self.fam.dims(cfg)
-        self.pcfg = pcfg or get_config(cfg["program_model"])
+        self.pcfg = program_model(cfg, pcfg)
         self.fam.check_program(self.pcfg, self.m)
+        self.chips = chips_of(cfg)
+        self.mesh = None
+        if "mesh" in cfg["engine"]:
+            from repro.launch.mesh import make_mesh_for
+            self.mesh = make_mesh_for(
+                self.chips, model_parallel=cfg["engine"]["mesh"]["model"])
         self.ev = None
         self.eng = None
         self.weights = None
@@ -137,17 +176,29 @@ class Harness:
 
     def load(self, seed: int) -> None:
         """Weights from the seed, handed to a fresh paged engine that
-        configures (and so compiles or loads) every bucket it dispatches."""
+        configures (and so compiles or loads) every bucket it dispatches.
+        On a mesh the weights are drawn sharded as the program's parameters,
+        after the last seed's are let go."""
         from repro.core.catalog import Variant
         from repro.core.config_graph import ConfigGraph
         from repro.models import registry as R
         from repro.serving.engine import EngineVariant, RealEngine
         jax = self.jax
         self.free()
-        self.weights = self.fam.make_weights(self.cfg, seed)
-        params = self.fam.to_program(self.weights, self.cfg)
         want = jax.eval_shape(
             lambda: R.init_params(jax.random.PRNGKey(0), self.pcfg))
+        if self.mesh is None:
+            self.weights = self.fam.make_weights(self.cfg, seed)
+        else:
+            from repro.sharding.rules import param_shardings
+            # two sets of weights would not fit: the last seed's go first,
+            # with the variant that holds the engine's placed copy of them
+            # (a fresh variant loads its programs from the compile cache)
+            self.weights = self.ev = None
+            gc.collect()
+            self.weights = self.fam.make_weights(
+                self.cfg, seed, param_shardings(want, self.pcfg, self.mesh))
+        params = self.fam.to_program(self.weights, self.cfg)
         got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
         if jax.tree.map(lambda a: (a.shape, a.dtype), want) != got:
             raise RuntimeError("weights do not fit the program's parameters")
@@ -166,8 +217,9 @@ class Harness:
             block_size=e["block_size"], n_blocks=e["n_blocks"],
             max_seqs=e["max_seqs"], chunk_blocks=e["chunk_blocks"],
             prefix_caching=e["prefix_caching"], policy=e["policy"],
-            fused_steps=e["fused_steps"])
-        self.graph = ConfigGraph.from_dict(self.pcfg.name, {("x1", 1): 1})
+            fused_steps=e["fused_steps"], mesh=self.mesh)
+        self.graph = ConfigGraph.from_dict(self.pcfg.name,
+                                           {("x1", self.chips): 1})
         self.eng.configure(self.graph)
         self._warm_eager()
 
@@ -278,7 +330,8 @@ class Harness:
                          for r in eng.drain()]
             stats = eng.stats()
         closed_s = time.perf_counter() - t_open
-        return {"cfg": self.cfg, "m": self.m, "seconds": seconds,
+        return {"cfg": self.cfg, "m": self.m, "chips": self.chips,
+                "seconds": seconds,
                 "requests": reqs, "responses": responses, "stats": stats,
                 "closed_s": closed_s, "lateness": late,
                 "compiles_in_window": compiles, "trace": None}

@@ -14,16 +14,35 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 
 import run as RUN  # noqa: E402
 
+# Cells whose files are under bench/ but which wait for their readings on
+# the chip (the knee, the program's and the control's gaps) before they
+# enter BENCHMARK.json.  The tests rehearse them with the others.
+QUEUED = {
+    "configs": [{"name": "glm4-9b", "file": "bench/configs/glm4-9b.json"}],
+    "workloads": [{"name": "glm4-9b.tp2x2-chat", "config": "glm4-9b",
+                   "traffic": "tp2x2-chat", "chips": 4}],
+}
+
+
+def benchmark():
+    """BENCHMARK.json with the queued cells and configurations beside its
+    own."""
+    bench = read_json(os.path.join(ROOT, "BENCHMARK.json"))
+    for key, queued in QUEUED.items():
+        have = {x["name"] for x in bench[key]}
+        bench[key] = bench[key] + [x for x in queued if x["name"] not in have]
+    return bench
+
 
 def smoke(workload, d_model=None, n_layers=None, vocab=None, dtype="bfloat16"):
     """The cell's spec with its configuration cut to smoke size, and the
     program model that matches it."""
     import jax.numpy as jnp
     from repro.configs import get_smoke_config
-    spec = copy.deepcopy(RUN.cell_of(
-        RUN.load_json(os.path.join(ROOT, "BENCHMARK.json")), workload))
+    spec = copy.deepcopy(RUN.cell_of(benchmark(), workload))
     cfg = spec["cfg"]
-    pc = get_smoke_config(cfg["program_model"]).with_(dtype=jnp.dtype(dtype))
+    pc = RUN.program_model(cfg, get_smoke_config(cfg["program_model"])
+                           ).with_(dtype=jnp.dtype(dtype))
     if d_model:
         pc = pc.with_(d_model=d_model, d_ff=4 * d_model)
     if n_layers:

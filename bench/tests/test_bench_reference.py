@@ -14,7 +14,11 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_smoke import smoke  # noqa: E402
 from models import dense_lm  # noqa: E402
 
-CELLS = ["qwen3-1.7b.chat-poisson", "qwen2-0.5b.batch-closed"]
+# Qwen3 (qk-norm, tied), Qwen2 (QKV bias, tied), GLM-4 (QKV bias, half of
+# each head rotated in interleaved pairs, 2 KV heads, untied head)
+CELLS = ["qwen3-1.7b.chat-poisson", "qwen2-0.5b.batch-closed",
+         "glm4-9b.tp2x2-chat"]
+GLM = CELLS[2]
 
 
 def model(workload, **size):
@@ -24,22 +28,38 @@ def model(workload, **size):
     return spec, pc, m, w
 
 
-@pytest.mark.parametrize("workload", CELLS)
-def test_reference_matches_the_program_forward(workload):
+def logits(workload):
+    """The program's and the reference's logits over one sequence."""
     from repro.models import registry as R
     spec, pc, m, w = model(workload)
     assert m["qk_norm"] == pc.qk_norm and m["qkv_bias"] == pc.qkv_bias
+    assert m["partial"] == pc.partial_rotary and m["tied"] == pc.tie_embeddings
     tokens = np.random.default_rng(0).integers(0, m["V"], 40).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         got, _ = R.forward(dense_lm.to_program(w, spec["cfg"]),
                            {"tokens": jnp.asarray(tokens)[None]}, pc)
         x = dense_lm.hidden(w, jnp.asarray(tokens), m)
         ref = x @ dense_lm.head(w, m).astype(jnp.float32)
-    got = np.asarray(got[0, :, :m["V"]], np.float32)
-    ref = np.asarray(ref)
+    return np.asarray(got[0, :, :m["V"]], np.float32), np.asarray(ref)
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_reference_matches_the_program_forward(workload):
+    got, ref = logits(workload)
     assert ref.std() > 0.1
     np.testing.assert_allclose(got, ref, atol=2e-4 * np.abs(ref).max(),
                                rtol=1e-4)
+
+
+def test_interleaved_rotary_is_laid_out_for_the_program(monkeypatch):
+    # without the reordering of wq/wk columns the program rotates other
+    # pairs than GLM does, and the logits part
+    spec, pc, m, w = model(GLM)
+    assert m["interleaved"] and m["partial"] == 0.5 and not m["tied"]
+    monkeypatch.setattr(dense_lm, "_rotary_order",
+                        lambda m: np.arange(m["dh"]))
+    got, ref = logits(GLM)
+    assert np.abs(got - ref).max() > 1e-2 * np.abs(ref).max()
 
 
 @pytest.mark.parametrize("workload", CELLS)

@@ -33,7 +33,7 @@ def requests(spec, seed, seconds=40.0, n_closed=300):
 
 def test_every_mix_is_a_known_kind():
     assert MIXES == ["batch-closed", "chat-poisson", "sessions-prefix",
-                     "short-burst"]
+                     "short-burst", "tp2x2-chat"]
     for name in MIXES:
         assert mix(name)["kind"] in ("open", "closed", "sessions")
 

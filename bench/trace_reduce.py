@@ -8,7 +8,11 @@
   its HLO instruction and result shape (``%fusion.206 = f32[1401,16,8,128]``);
   loops and calls, which contain other operations, are left out of it;
 * ``idle_gaps``: idle device time by what the host was doing meanwhile, as
-  the harness's own spans (``bench.*``) name it.
+  the harness's own spans (``bench.*``) name it;
+* ``collective_s``: the union of the intervals in which a collective
+  operation ran (all-reduce, all-gather, reduce-scatter, collective-permute,
+  all-to-all, or their ``-start``/``-done`` halves, by instruction name or
+  opcode), inside the window, averaged over the devices used.
 
 Device planes are those named ``/device:TPU:<n>``; on each, operations are
 the events of the line ``XLA Ops``.  Host spans are events of any line of
@@ -20,6 +24,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -28,6 +33,8 @@ HOST_PREFIX = "bench."
 OPS_LINE = "XLA Ops"
 IDLE = "(no bench span)"
 CONTAINERS = ("while", "conditional", "call")
+COLLECTIVE = re.compile(r"(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)")
 
 Interval = Tuple[float, float]
 
@@ -49,6 +56,12 @@ def op_name(hlo: str) -> str:
 def _container(hlo: str) -> bool:
     rhs = hlo.split(" = ", 1)[-1]
     return any(f" {c}(" in rhs or rhs.startswith(f"{c}(") for c in CONTAINERS)
+
+
+def _collective(hlo: str) -> bool:
+    """Whether the instruction's name or opcode (the text before its
+    operands) names a collective."""
+    return bool(COLLECTIVE.search(hlo.split("(", 1)[0]))
 
 
 def _union(iv: Sequence[Interval]) -> List[Interval]:
@@ -124,13 +137,17 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
     by_op: Dict[str, float] = defaultdict(float)
     by_host: Dict[str, float] = defaultdict(float)
     host = _Spans(spans)
-    busy_ns = 0.0
+    busy_ns = coll_ns = 0.0
     for ops in devices:
+        coll = []
         for s, e, name in ops:
             if e > lo and s < hi and not _container(name):
                 by_op[op_name(name)] += min(e, hi) - max(s, lo)
+                if _collective(name):
+                    coll.append((s, e))
         busy = _union(_clip([(s, e) for s, e, _ in ops], lo, hi))
         busy_ns += sum(e - s for s, e in busy)
+        coll_ns += sum(e - s for s, e in _union(_clip(coll, lo, hi)))
         for g in _gaps(busy, lo, hi):
             by_host[host.label(g)] += g[1] - g[0]
     nd = len(devices)
@@ -141,7 +158,8 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
         return [[k, v / nd / 1e9] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
     return {"busy_s": busy_ns / nd / 1e9, "window_s": (hi - lo) / 1e9,
-            "device_ops": ranked(by_op), "idle_gaps": ranked(by_host)}
+            "device_ops": ranked(by_op), "idle_gaps": ranked(by_host),
+            "collective_s": coll_ns / nd / 1e9}
 
 
 def reduce_dir(trace_dir: str, n_devices: int = 1) -> Optional[Dict]:
