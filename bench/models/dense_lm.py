@@ -26,6 +26,10 @@ program rotates halves, so for an interleaved model ``to_program`` reorders
 each head's rotated columns of ``wq``, ``wk`` and their biases (pair ``i``
 to columns ``i`` and ``i + r/2``).  Queries and keys are reordered alike,
 so every attention score, and so every logit, is the same.
+
+A family module also gives the model FLOPs that ``step_mfu`` counts:
+``prefill(m, prompt, cached)`` and ``decode_token(m, ctx)``; a dense
+decoder's are ``bench/flops.py``'s.
 """
 from __future__ import annotations
 
@@ -34,6 +38,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from flops import decode_token, prefill  # noqa: F401  (the family's FLOPs)
 
 F32 = jnp.float32
 

@@ -4,6 +4,8 @@ A run record is a dict:
 
 * ``cfg``, ``m``: the configuration file and its model dimensions
   (``models/<family>.dims``);
+* ``family``: the configuration's family module (``models/<family>.py``),
+  whose ``prefill`` and ``decode_token`` count the model's FLOPs;
 * ``chips``: the chips the engine spans;
 * ``seconds``: the measured window;
 * ``requests``: one dict per request sent in the window: ``t_s`` (scheduled

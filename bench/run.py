@@ -330,8 +330,8 @@ class Harness:
                          for r in eng.drain()]
             stats = eng.stats()
         closed_s = time.perf_counter() - t_open
-        return {"cfg": self.cfg, "m": self.m, "chips": self.chips,
-                "seconds": seconds,
+        return {"cfg": self.cfg, "m": self.m, "family": self.fam,
+                "chips": self.chips, "seconds": seconds,
                 "requests": reqs, "responses": responses, "stats": stats,
                 "closed_s": closed_s, "lateness": late,
                 "compiles_in_window": compiles, "trace": None}

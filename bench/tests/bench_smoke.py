@@ -78,6 +78,16 @@ def read_json(path):
         return json.load(f)
 
 
+def trace_fixture():
+    """``fixtures/trace_small.pbtxt``, a hand-made TPU-like profile, as
+    ``jax.profiler.ProfileData``."""
+    from jax.profiler import ProfileData
+    with open(os.path.join(BENCH, "tests", "fixtures",
+                           "trace_small.pbtxt")) as f:
+        text = "".join(l for l in f if not l.lstrip().startswith("#"))
+    return ProfileData.from_text_proto(text)
+
+
 class CopyingUploads:
     """``jax.numpy`` for the engine module, except that ``asarray`` copies
     a numpy array first.  On the CPU backend ``jnp.asarray`` of a numpy

@@ -1,4 +1,5 @@
-"""bench/flops.py against a count by hand, for both configurations."""
+"""bench/flops.py against a count by hand, for both configurations, and
+the family module's FLOPs, which ``step_mfu`` reads, against it."""
 import json
 import os
 import sys
@@ -9,12 +10,17 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
 import flops  # noqa: E402
+import run as RUN  # noqa: E402
 from models import dense_lm  # noqa: E402
 
 
-def dims(name):
+def config(name):
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
-        return dense_lm.dims(json.load(f))
+        return json.load(f)
+
+
+def dims(name):
+    return dense_lm.dims(config(name))
 
 
 # per layer: q (d x H dh), k and v (d x K dh), o (H dh x d), gate/up/down
@@ -59,3 +65,14 @@ def test_prefill_and_decode_are_sums_over_tokens(name):
     steps = sum(flops.decode_token(m, p + j) for j in range(1, n))
     assert flops.decode(m, p, n) == pytest.approx(steps, rel=1e-12)
     assert flops.decode(m, p, 1) == 0
+
+
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "qwen2-0.5b", "glm4-9b"])
+def test_the_family_counts_a_dense_decoders_flops(name):
+    cfg = config(name)
+    fam, m = RUN.family(cfg), dims(name)
+    for p, c in ((1, 0), (37, 0), (1024, 512.5), (1536, 1024)):
+        assert fam.prefill(m, p, c) == flops.prefill(m, p, c)
+        assert fam.prefill(m, p) == flops.prefill(m, p)
+    for ctx in (1, 513, 2047):
+        assert fam.decode_token(m, ctx) == flops.decode_token(m, ctx)

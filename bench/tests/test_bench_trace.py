@@ -1,25 +1,24 @@
 """trace_reduce on a small recorded trace: busy time is the union of device
 operations inside the window span, idle time is named by the host span that
 overlaps each gap most, the ranking sums each operation's time, and
-collective time is the union of the collectives' intervals."""
+collective time is the union of the collectives' intervals.  For the
+per-layer readers it keeps every operation, kernels by name, each device
+program's time and events, and each program span's count, time and summed
+arguments, all inside the window."""
 import os
 import sys
 
 import pytest
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from bench_smoke import trace_fixture  # noqa: E402
 import trace_reduce  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def profile():
-    from jax.profiler import ProfileData
-    with open(os.path.join(BENCH, "tests", "fixtures",
-                           "trace_small.pbtxt")) as f:
-        text = "".join(l for l in f if not l.lstrip().startswith("#"))
-    return ProfileData.from_text_proto(text)
+    return trace_fixture()
 
 
 def test_busy_and_window_on_one_chip(profile):
@@ -101,3 +100,85 @@ def test_collective_time_is_the_union_of_collectives_in_the_window():
 
 def test_a_trace_without_collectives_reads_none_of_their_time(profile):
     assert trace_reduce.reduce(profile, n_devices=2)["collective_s"] == 0.0
+
+
+# What the parent of the per-layer keys read on this fixture, on one chip
+# and on two: the keys that the breakdown prints and accepted metrics read
+# stay bit for bit.
+PARENT = {
+    1: {"busy_s": 0.004, "window_s": 0.01, "collective_s": 0.0,
+        "device_ops": [["%fusion.1 = f32[28,1401,16,8,128]", 0.003],
+                       ["%convolution.2 = bf16[32,2048]", 0.001],
+                       ["%copy.3 = s32[8]", 0.0005]],
+        "idle_gaps": [["bench.sleep", 0.005], ["bench.step", 0.001]]},
+    2: {"busy_s": 0.007, "window_s": 0.01, "collective_s": 0.0,
+        "device_ops": [["fusion.7", 0.005],
+                       ["%fusion.1 = f32[28,1401,16,8,128]", 0.0015],
+                       ["%convolution.2 = bf16[32,2048]", 0.0005],
+                       ["%paged_attention.5 = bf16[8,16,128]", 0.0005],
+                       ["%copy.3 = s32[8]", 0.00025]],
+        "idle_gaps": [["bench.sleep", 0.0025], ["bench.step", 0.0005]]},
+}
+
+
+@pytest.mark.parametrize("chips", sorted(PARENT))
+def test_printed_and_accepted_keys_read_as_before(profile, chips):
+    r = trace_reduce.reduce(profile, n_devices=chips)
+    assert {k: r[k] for k in PARENT[chips]} == PARENT[chips]
+
+
+def test_modules_are_device_time_and_events_by_program(profile):
+    r = trace_reduce.reduce(profile, n_devices=1)
+    # prefill [-0.5, 2] counts its 2 ms inside the window; decode [3, 4.5]
+    # and [9.5, 11] count 1.5 + 0.5 ms, two events; ids dropped from names
+    assert r["modules"] == {
+        "jit_prefill_paged": {"seconds": pytest.approx(0.002), "count": 1},
+        "jit_decode_multi": {"seconds": pytest.approx(0.002), "count": 2}}
+    # every program inside the window, as busy time is here
+    assert sum(m["seconds"] for m in r["modules"].values()) == \
+        pytest.approx(r["busy_s"])
+    # averaged over the chips used: the second has no program line
+    two = trace_reduce.reduce(profile, n_devices=2)["modules"]
+    assert two["jit_decode_multi"] == {"seconds": pytest.approx(0.001),
+                                       "count": 1}
+
+
+def test_program_spans_count_time_and_sum_their_arguments(profile):
+    r = trace_reduce.reduce(profile, n_devices=1)
+    # the dispatch before the window is left out, the one across its end
+    # counts its 0.2 ms inside; a text argument is not summed, and the
+    # harness's own spans are not program spans
+    assert r["spans"] == {"engine.decode_dispatch": {
+        "count": 3, "seconds": pytest.approx(0.0006),
+        "args": {"k": 11, "rows": 7}}}
+
+
+def test_ops_hold_every_operation_and_kernels_by_name(profile):
+    one = trace_reduce.reduce(profile, n_devices=1, top=1)
+    assert len(one["device_ops"]) == 1
+    assert one["ops"] == {"%fusion.1 = f32[28,1401,16,8,128]":
+                          pytest.approx(0.003),
+                          "%convolution.2 = bf16[32,2048]": pytest.approx(0.001),
+                          "%copy.3 = s32[8]": pytest.approx(0.0005)}
+    two = trace_reduce.reduce(profile, n_devices=2)["ops"]
+    # the second chip's Pallas kernel, under its instruction and its name
+    assert two["%paged_attention.5 = bf16[8,16,128]"] == pytest.approx(0.0005)
+    assert two["paged_attention"] == pytest.approx(0.0005)
+    assert two["fusion.7"] == pytest.approx(0.005)
+    assert len(two) == 6
+
+
+def test_kernel_name_of_a_custom_call():
+    plain = ('%flash_fwd.12 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} '
+             '%x), custom_call_target="tpu_custom_call"')
+    assert trace_reduce.kernel_name(plain) == "flash_fwd"
+    named = plain + ', kernel_name="paged_decode"'
+    assert trace_reduce.kernel_name(named) == "paged_decode"
+    assert trace_reduce.kernel_name(
+        "%fusion.3 = f32[8]{0} fusion(f32[8]{0} %custom-call.2)") is None
+    # XLA's own custom calls carry no kernel name
+    assert trace_reduce.kernel_name(
+        '%custom-call.20 = s32[8,64]{1,0} custom-call(f32[8,64]{1,0} %x), '
+        'custom_call_target="TopK"') is None
+    assert trace_reduce.module_name("jit_decode_multi(123)") == \
+        "jit_decode_multi"

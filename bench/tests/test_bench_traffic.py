@@ -1,5 +1,7 @@
 """The traffic generator: deterministic by seed, the stated distributions
-and clipping, the open-loop schedule and the closed-loop client count."""
+and clipping, the open-loop schedule and the closed-loop client count.
+Every mix file is of a kind the generator knows, and every cell names a mix
+that exists, so that a mix is added as a file of its own."""
 import glob
 import json
 import os
@@ -13,10 +15,15 @@ sys.path.insert(0, BENCH)
 
 import traffic_gen as TG  # noqa: E402
 
+sys.path.insert(0, os.path.join(BENCH, "tests"))
+from bench_smoke import QUEUED, benchmark  # noqa: E402
+
 VOCAB = 151936
 SEED = 2**31 + 12345          # seeds pass 32 signed bits
+TRAFFIC = os.path.join(BENCH, "traffic")
 MIXES = sorted(os.path.basename(p)[:-5]
-               for p in glob.glob(os.path.join(BENCH, "traffic", "*.json")))
+               for p in glob.glob(os.path.join(TRAFFIC, "*.json")))
+KINDS = ("open", "closed", "sessions")
 
 
 def mix(name):
@@ -31,11 +38,43 @@ def requests(spec, seed, seconds=40.0, n_closed=300):
     return [src.next(0.0) for _ in range(n_closed)]
 
 
+def unknown_kinds(traffic_dir):
+    """The mix files under ``traffic_dir`` that do not load, or whose kind
+    the generator does not know."""
+    bad = []
+    for path in sorted(glob.glob(os.path.join(traffic_dir, "*.json"))):
+        try:
+            with open(path) as f:
+                ok = json.load(f).get("kind") in KINDS
+        except ValueError:
+            ok = False
+        if not ok:
+            bad.append(os.path.basename(path))
+    return bad
+
+
+def missing_mixes(bench, traffic_dir):
+    """The cells of ``bench`` whose mix has no file under ``traffic_dir``."""
+    return [w["name"] for w in bench["workloads"]
+            if not os.path.isfile(os.path.join(traffic_dir,
+                                               w["traffic"] + ".json"))]
+
+
 def test_every_mix_is_a_known_kind():
-    assert MIXES == ["batch-closed", "chat-poisson", "sessions-prefix",
-                     "short-burst", "tp2x2-chat"]
-    for name in MIXES:
-        assert mix(name)["kind"] in ("open", "closed", "sessions")
+    assert MIXES and unknown_kinds(TRAFFIC) == []
+    # BENCHMARK.json's cells and the queued ones
+    assert missing_mixes(benchmark(), TRAFFIC) == []
+    assert missing_mixes(QUEUED, TRAFFIC) == []
+
+
+def test_the_mix_checks_refuse_an_unknown_kind_and_a_missing_mix(tmp_path):
+    (tmp_path / "ok.json").write_text(json.dumps(mix("chat-poisson")))
+    (tmp_path / "odd.json").write_text(json.dumps({"kind": "replay"}))
+    (tmp_path / "torn.json").write_text('{"kind": "open"')
+    assert unknown_kinds(str(tmp_path)) == ["odd.json", "torn.json"]
+    cells = {"workloads": [{"name": "a.ok", "traffic": "ok"},
+                           {"name": "a.gone", "traffic": "gone"}]}
+    assert missing_mixes(cells, str(tmp_path)) == ["a.gone"]
 
 
 @pytest.mark.parametrize("name", MIXES)

@@ -14,10 +14,31 @@
   all-to-all, or their ``-start``/``-done`` halves, by instruction name or
   opcode), inside the window, averaged over the devices used.
 
+``device_ops`` and ``idle_gaps`` are what a run prints as its breakdown.
+Three more keys are for the per-layer readers (``bench/metrics``), and grow
+with the trace, so a run never prints them:
+
+* ``modules``: for each XLA module (``jit_decode_multi``, named without
+  the ``(<id>)`` suffix), ``seconds``, its events' device time inside the
+  window, and ``count``, its events that overlap the window, both averaged
+  over the devices used;
+* ``spans``: for each program span (``engine.*``) that overlaps the window,
+  ``count``, ``seconds`` inside the window, and ``args``, the sum of each
+  numeric argument over its events (``k`` of ``engine.decode_dispatch``:
+  the decode steps dispatched);
+* ``ops``: device time of every operation that ``device_ops`` ranks, not
+  only the longest, averaged over the devices used.  A custom call (a
+  Pallas or Mosaic kernel) also counts under its kernel's name
+  (``kernel_name`` of its HLO text where it carries one, else its
+  instruction's name without ``%`` and the ``.<n>`` suffix, which Pallas
+  sets to the kernel's name), so its time shows twice in the sum over all
+  keys.
+
 Device planes are those named ``/device:TPU:<n>``; on each, operations are
-the events of the line ``XLA Ops``.  Host spans are events of any line of
-the plane ``/host:CPU``.  ProfileData puts every plane's events on one
-clock, so device and host intervals compare directly.
+the events of the line ``XLA Ops`` and programs those of ``XLA Modules``.
+Host spans are events of any line of the plane ``/host:CPU``.  ProfileData
+puts every plane's events on one clock, so device and host intervals
+compare directly.
 """
 from __future__ import annotations
 
@@ -25,16 +46,20 @@ import bisect
 import glob
 import os
 import re
+import warnings
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW = "bench.window"
 HOST_PREFIX = "bench."
+SPAN_PREFIX = "engine."
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 IDLE = "(no bench span)"
 CONTAINERS = ("while", "conditional", "call")
 COLLECTIVE = re.compile(r"(all-reduce|all-gather|reduce-scatter|"
                         r"collective-permute|all-to-all)")
+KERNEL_NAME = re.compile(r'kernel_name="([^"]+)"')
 
 Interval = Tuple[float, float]
 
@@ -51,6 +76,34 @@ def op_name(hlo: str) -> str:
     """``%fusion.206 = f32[1401,16,8,128]{...} fusion(...)`` ->
     ``%fusion.206 = f32[1401,16,8,128]``."""
     return hlo.split("{", 1)[0].split(" fusion(", 1)[0].strip()
+
+
+def module_name(name: str) -> str:
+    """``jit_decode_multi(15611547856741370565)`` -> ``jit_decode_multi``."""
+    return name.split("(", 1)[0]
+
+
+def kernel_name(hlo: str) -> Optional[str]:
+    """The kernel a custom call runs, by the name its HLO text carries;
+    None for any other operation, and for a custom call that carries none
+    (an instruction named ``custom-call``, as XLA's own are)."""
+    if " custom-call(" not in hlo:
+        return None
+    named = KERNEL_NAME.search(hlo)
+    if named:
+        return named.group(1)
+    name = re.sub(r"\.\d+$", "", hlo.split(" = ", 1)[0].strip().lstrip("%"))
+    return None if name == "custom-call" else name
+
+
+def _numeric(ev) -> Dict[str, float]:
+    """An event's numeric arguments (its profiler stats)."""
+    with warnings.catch_warnings():
+        # the first read of an event's stats warns about their builtin type
+        warnings.simplefilter("ignore", DeprecationWarning)
+        stats = list(ev.stats)
+    return {k: v for k, v in stats
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
 
 
 def _container(hlo: str) -> bool:
@@ -115,7 +168,9 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
     operation or no window span is found."""
     window: Optional[Interval] = None
     spans: List[Tuple[float, float, str]] = []
+    program: List[Tuple[float, float, str, Dict[str, float]]] = []
     devices: List[List[Tuple[float, float, str]]] = []
+    modules: List[List[Tuple[float, float, str]]] = []
     for plane in pd.planes:
         if plane.name.startswith("/host:CPU"):
             for line in plane.lines:
@@ -124,17 +179,25 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
                         window = (ev.start_ns, ev.end_ns)
                     elif ev.name.startswith(HOST_PREFIX):
                         spans.append((ev.start_ns, ev.end_ns, ev.name))
+                    elif ev.name.startswith(SPAN_PREFIX):
+                        program.append((ev.start_ns, ev.end_ns, ev.name,
+                                        _numeric(ev)))
         elif plane.name.startswith("/device:TPU:"):
             ops = [(ev.start_ns, ev.end_ns, ev.name)
                    for line in plane.lines if line.name == OPS_LINE
                    for ev in line.events]
             if ops:
                 devices.append(ops)
+                modules.append([(ev.start_ns, ev.end_ns, ev.name)
+                                for line in plane.lines
+                                if line.name == MODULES_LINE
+                                for ev in line.events])
     if window is None or not devices:
         return None
     devices = devices[:n_devices]
     lo, hi = window
     by_op: Dict[str, float] = defaultdict(float)
+    by_kernel: Dict[str, float] = defaultdict(float)
     by_host: Dict[str, float] = defaultdict(float)
     host = _Spans(spans)
     busy_ns = coll_ns = 0.0
@@ -143,6 +206,9 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
         for s, e, name in ops:
             if e > lo and s < hi and not _container(name):
                 by_op[op_name(name)] += min(e, hi) - max(s, lo)
+                kernel = kernel_name(name)
+                if kernel:
+                    by_kernel[kernel] += min(e, hi) - max(s, lo)
                 if _collective(name):
                     coll.append((s, e))
         busy = _union(_clip([(s, e) for s, e, _ in ops], lo, hi))
@@ -159,7 +225,43 @@ def reduce(pd, n_devices: int = 1, top: int = 10) -> Optional[Dict]:
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
     return {"busy_s": busy_ns / nd / 1e9, "window_s": (hi - lo) / 1e9,
             "device_ops": ranked(by_op), "idle_gaps": ranked(by_host),
-            "collective_s": coll_ns / nd / 1e9}
+            "collective_s": coll_ns / nd / 1e9,
+            "modules": _modules(modules[:nd], lo, hi),
+            "spans": _program_spans(program, lo, hi),
+            "ops": {k: v / nd / 1e9 for d in (by_op, by_kernel)
+                    for k, v in d.items()}}
+
+
+def _modules(planes, lo: float, hi: float) -> Dict[str, Dict[str, float]]:
+    """Device seconds and events of each module inside ``[lo, hi)``,
+    averaged over the planes."""
+    ns: Dict[str, float] = defaultdict(float)
+    count: Dict[str, int] = defaultdict(int)
+    for events in planes:
+        for s, e, name in events:
+            if e > lo and s < hi:
+                ns[module_name(name)] += min(e, hi) - max(s, lo)
+                count[module_name(name)] += 1
+    n = len(planes)
+    return {k: {"seconds": v / n / 1e9, "count": count[k] / n}
+            for k, v in ns.items()}
+
+
+def _program_spans(program, lo: float, hi: float) -> Dict[str, Dict]:
+    """Count, seconds inside ``[lo, hi)`` and summed numeric arguments of
+    each program span that overlaps it."""
+    out: Dict[str, Dict] = {}
+    for s, e, name, args in program:
+        if e > lo and s < hi:
+            sp = out.setdefault(name, {"count": 0, "seconds": 0.0,
+                                       "args": defaultdict(float)})
+            sp["count"] += 1
+            sp["seconds"] += (min(e, hi) - max(s, lo)) / 1e9
+            for k, v in args.items():
+                sp["args"][k] += v
+    for sp in out.values():
+        sp["args"] = dict(sp["args"])
+    return out
 
 
 def reduce_dir(trace_dir: str, n_devices: int = 1) -> Optional[Dict]:
